@@ -45,7 +45,7 @@ from .common import row, smoke, time_call
 
 # NOTE: deliberately f32 — run.py executes every bench in one process, so
 # flipping jax_enable_x64 here would leak into the other benches (only
-# bench_tolerance runs subprocessed).  Tolerances below sit above f32 noise.
+# bench_tolerance is a command of its own).  Tolerances below sit above f32 noise.
 
 
 def field(state, t, params):

@@ -25,19 +25,19 @@ approaches it on a multi-core host; on a single-core container the forced
 host devices serialize and the measured number reflects only the wasted-
 work reduction).
 
-Standalone (preferred — the device flag must precede jax init):
+A command of its own (the device flag must precede jax init, so
+``benchmarks.run`` does not run it):
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python -m benchmarks.bench_shard [--smoke]
 
-writes BENCH_bench_shard.json itself; ``benchmarks.run`` wraps it in a
-subprocess with the flag set and lifts the rows into its own dump.
+writes BENCH_bench_shard.json.
 """
 from __future__ import annotations
 
-import json
 import os
 import sys
+import time
 
 # must happen before jax initializes its backend; harmless if the parent
 # already set a device count (standalone CI invocation does).
@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import AdaptiveConfig, SaveAt, solve
-from .common import get_records, row, smoke, time_call
+from .common import dump_bench_json, row, smoke, time_call
 
 
 def field(state, t, params):
@@ -138,17 +138,12 @@ def main() -> None:
         speedup=round(s_gbase / s_gshard, 2))
 
 
-def _dump_standalone() -> None:
-    payload = {"bench": "bench_shard", "smoke": smoke(), "ok": True,
-               "devices": len(jax.devices()), "rows": get_records()}
-    with open("BENCH_bench_shard.json", "w") as fh:
-        json.dump(payload, fh, indent=1)
-    print(f"# wrote BENCH_bench_shard.json ({len(payload['rows'])} rows)",
-          flush=True)
-
-
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
     if "--smoke" in sys.argv:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
+    enable_compile_cache()
+    t_start = time.time()
     main()
-    _dump_standalone()
+    dump_bench_json("bench_shard", time.time() - t_start, True,
+                    devices=len(jax.devices()))

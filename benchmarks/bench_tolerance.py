@@ -5,6 +5,11 @@ loosened (the backward integration diverges from the forward), while the
 symplectic adjoint returns the exact gradient of whatever discrete forward
 map the tolerance produced.  We measure relative gradient error against a
 float64 tight-tolerance oracle across atol in {1e-8 .. 1e-3}.
+
+It turns on x64 for its whole process, so it is a command of its own
+(``benchmarks.run`` does not run it) that writes BENCH_bench_tolerance.json:
+
+    python -m benchmarks.bench_tolerance [--smoke]
 """
 from __future__ import annotations
 
@@ -14,7 +19,7 @@ import numpy as np
 
 from repro.core import (AdaptiveConfig, ContinuousAdjoint, SymplecticAdjoint,
                         solve)
-from .common import row, smoke
+from .common import dump_bench_json, row, smoke
 
 jax.config.update("jax_enable_x64", True)
 
@@ -81,4 +86,13 @@ def main():
 
 
 if __name__ == "__main__":
+    import os
+    import sys
+    import time
+    from repro.runtime.compile_cache import enable_compile_cache
+    if "--smoke" in sys.argv:
+        os.environ["REPRO_BENCH_SMOKE"] = "1"
+    enable_compile_cache()
+    t_start = time.time()
     main()
+    dump_bench_json("bench_tolerance", time.time() - t_start, True)

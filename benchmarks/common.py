@@ -8,6 +8,7 @@ optimization step), and it is what a TPU deployment must fit in HBM.
 """
 from __future__ import annotations
 
+import json
 import os
 import time
 
@@ -42,6 +43,16 @@ def get_records() -> list:
 
 def record(name: str, **metrics) -> None:
     _RECORDS.append({"name": name, **metrics})
+
+
+def dump_bench_json(name: str, wall_s: float, ok: bool, **extra) -> None:
+    """Write ``BENCH_<name>.json``: wall time and the recorded rows."""
+    payload = {"bench": name, "smoke": smoke(), "ok": ok,
+               "wall_s": round(wall_s, 2), **extra, "rows": get_records()}
+    path = f"BENCH_{name}.json"
+    with open(path, "w") as fh:
+        json.dump(payload, fh, indent=1)
+    print(f"# wrote {path} ({len(payload['rows'])} rows)", flush=True)
 
 
 def smoke() -> bool:

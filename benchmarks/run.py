@@ -6,7 +6,6 @@ steady-state / compile-time metrics) so the perf trajectory is a queryable
 artifact, not just job logs.  CI uploads ``BENCH_*.json`` from the
 ``--smoke`` job on every push.
 
-  bench_tolerance       -> Fig. 1  (gradient error vs tolerance)
   bench_steps           -> Fig. 2  (memory vs number of steps)
   bench_orders          -> Table 1 (memory scaling orders in N, s, L)
   bench_cnf             -> Table 2 (CNF: NLL / memory / time per grad method)
@@ -18,8 +17,6 @@ artifact, not just job logs.  CI uploads ``BENCH_*.json`` from the
   bench_serve           -> continuous-batching engine vs sequential solving
   bench_checkpoint      -> blocking vs async checkpoint save stall, overlap
                            with compute, restore throughput (docs/training.md)
-  bench_shard           -> mesh-sharded lanes vs 1 device (subprocess: the
-                           forced host-device flag must precede jax init)
   roofline              -> EXPERIMENTS.md roofline (reads runs/dryrun.jsonl)
 
 Usage:
@@ -27,12 +24,20 @@ Usage:
 
 ``--smoke`` sets REPRO_BENCH_SMOKE=1 so every benchmark runs at tiny
 rot-check sizes (CI executes this on every push; see .github/workflows).
+
+This driver runs every bench in this one process and starts no child
+process: a chip belongs to the one process that touched JAX first.  Two
+benches need a process configured differently, so each is a command of
+its own that writes its own ``BENCH_<name>.json``:
+
+    python -m benchmarks.bench_tolerance [--smoke]   # Fig. 1; turns on x64
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python -m benchmarks.bench_shard [--smoke]  # mesh-sharded lanes vs
+                                                     # 1 device
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
 import sys
 import time
 import traceback
@@ -40,77 +45,22 @@ import traceback
 from . import common
 
 
-def _tolerance_subprocess():
-    # bench_tolerance enables x64 globally; isolate it in a subprocess so
-    # the f32 benches in this process are unaffected.  (Its rows are
-    # recorded in the child process, so its BENCH json carries wall time
-    # only.)
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_tolerance"],
-        capture_output=True, text=True, timeout=1200)
-    sys.stdout.write(out.stdout)
-    if out.returncode != 0:
-        sys.stderr.write(out.stderr[-2000:])
-        raise RuntimeError("bench_tolerance failed")
-
-
-def _shard_subprocess():
-    # bench_shard needs forced host devices, and the device-count flag only
-    # takes effect BEFORE jax initializes its backend — this process's jax
-    # is already up single-device, so the bench runs standalone.  The child
-    # writes its own BENCH_bench_shard.json; lift its rows into this
-    # process's records so the parent dump (which overwrites that file)
-    # preserves them.
-    env = dict(os.environ)
-    if "--xla_force_host_platform_device_count" not in \
-            env.get("XLA_FLAGS", ""):
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8").strip()
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_shard"],
-        env=env, capture_output=True, text=True, timeout=1800)
-    sys.stdout.write(out.stdout)
-    if out.returncode != 0:
-        sys.stderr.write(out.stderr[-2000:])
-        raise RuntimeError("bench_shard failed")
-    try:
-        with open("BENCH_bench_shard.json") as fh:
-            for rec in json.load(fh).get("rows", []):
-                common.record(**rec)
-    except (FileNotFoundError, json.JSONDecodeError):
-        pass
-
-
-def _dump_bench_json(name: str, wall_s: float, ok: bool) -> None:
-    payload = {
-        "bench": name,
-        "smoke": common.smoke(),
-        "ok": ok,
-        "wall_s": round(wall_s, 2),
-        "rows": common.get_records(),
-    }
-    path = f"BENCH_{name}.json"
-    with open(path, "w") as fh:
-        json.dump(payload, fh, indent=1)
-    print(f"# wrote {path} ({len(payload['rows'])} rows)", flush=True)
-
-
 def main() -> None:
     args = sys.argv[1:]
     if "--smoke" in args:
         args.remove("--smoke")
-        # env (not a flag) so the bench_tolerance subprocess inherits it
         os.environ["REPRO_BENCH_SMOKE"] = "1"
         print("# smoke mode: rot-check sizes, numbers are meaningless",
               flush=True)
+
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from . import (bench_batch, bench_checkpoint, bench_cnf, bench_combine,
                    bench_orders, bench_physics, bench_rk_sweep,
                    bench_saveat_compile, bench_serve, bench_steps, roofline)
 
     benches = [
-        ("bench_tolerance", _tolerance_subprocess),
         ("bench_steps", bench_steps.main),
         ("bench_orders", bench_orders.main),
         ("bench_cnf", bench_cnf.main),
@@ -121,7 +71,6 @@ def main() -> None:
         ("bench_batch", bench_batch.main),
         ("bench_serve", bench_serve.main),
         ("bench_checkpoint", bench_checkpoint.main),
-        ("bench_shard", _shard_subprocess),
         ("roofline", roofline.main),
     ]
     only = args[0] if args else None
@@ -141,7 +90,7 @@ def main() -> None:
             traceback.print_exc()
         wall = time.time() - t0
         print(f"# {name} done in {wall:.1f}s", flush=True)
-        _dump_bench_json(name, wall, ok)
+        common.dump_bench_json(name, wall, ok)
     if failed:
         print(f"# FAILED: {failed}")
         sys.exit(1)

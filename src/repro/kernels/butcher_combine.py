@@ -25,7 +25,13 @@ x_{n+1}-x_n]) both flow through the same single-row kernel.
 
 Tiling: the state is reshaped to (rows, 128) lanes; each grid step processes
 a (block_rows, 128) tile of x and the matching (s, block_rows, 128) tile of
-ks — the (8, 128) float32 VREG layout and VMEM budget set block_rows.
+ks — the (8, 128) float32 VREG layout and VMEM budget set block_rows.  The
+coefficients live whole in SMEM (scalar memory) as 2-D (1, s) / (m, s)
+arrays: they are per-lane data when the batched adaptive driver vmaps a
+step over lanes, and vmap then adds a leading lane axis; a 1-D (s,) array
+would become a (lanes, s) array whose squeezed lane block breaks Mosaic's
+(8, 128) block rule, while a 2-D one keeps blocks equal to its last two
+dims.
 
 Accumulation is ``promote_types(x.dtype, float32)`` (f32 for f32/bf16
 states, f64 for f64 states under x64), strictly in stage order
@@ -40,6 +46,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 
@@ -60,7 +67,7 @@ def _pad_to_tiles(x, ks, block_rows):
 def _kernel(coef_ref, x_ref, ks_ref, o_ref, *, s: int, acc_dt):
     acc = x_ref[...].astype(acc_dt)
     for i in range(s):  # unrolled: s is a small static constant (<= 13)
-        acc = acc + coef_ref[i].astype(acc_dt) * ks_ref[i].astype(acc_dt)
+        acc = acc + coef_ref[0, i].astype(acc_dt) * ks_ref[i].astype(acc_dt)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -69,20 +76,20 @@ def _kernel(coef_ref, x_ref, ks_ref, o_ref, *, s: int, acc_dt):
 def butcher_combine_pallas(x: jnp.ndarray, ks: jnp.ndarray,
                            coefs: jnp.ndarray, h: jnp.ndarray,
                            *, block_rows: int = 256,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """x: (...,); ks: (s, ...); coefs: (s,); h: scalar."""
     s = ks.shape[0]
     orig_shape = x.shape
     xf, kf, rows_pad, n = _pad_to_tiles(x, ks, block_rows)
     acc_dt = jnp.promote_types(x.dtype, jnp.float32)
-    hc = (h * coefs).astype(acc_dt)
+    hc = (h * coefs).astype(acc_dt).reshape(1, s)
 
     grid = (rows_pad // block_rows,)
     out = pl.pallas_call(
         functools.partial(_kernel, s=s, acc_dt=acc_dt),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((s,), lambda r: (0,)),                 # coefs
+            pl.BlockSpec(memory_space=pltpu.SMEM),              # coefs
             pl.BlockSpec((block_rows, LANE), lambda r: (r, 0)),  # x tile
             pl.BlockSpec((s, block_rows, LANE),
                          lambda r: (0, r, 0)),                   # ks tile
@@ -98,7 +105,7 @@ def _rows_kernel(coef_ref, scale_ref, x_ref, ks_ref, o_ref,
                  *, s: int, m: int, acc_dt):
     x = x_ref[...].astype(acc_dt)
     for r in range(m):  # unrolled: m is tiny (2 for update+error)
-        acc = scale_ref[r].astype(acc_dt) * x
+        acc = scale_ref[0, r].astype(acc_dt) * x
         for i in range(s):
             acc = acc + coef_ref[r, i].astype(acc_dt) * \
                 ks_ref[i].astype(acc_dt)
@@ -110,7 +117,7 @@ def _rows_kernel(coef_ref, scale_ref, x_ref, ks_ref, o_ref,
 def butcher_combine_rows_pallas(x: jnp.ndarray, ks: jnp.ndarray,
                                 coefs: jnp.ndarray, base_scale: jnp.ndarray,
                                 h: jnp.ndarray, *, block_rows: int = 256,
-                                interpret: bool = True) -> jnp.ndarray:
+                                interpret: bool = False) -> jnp.ndarray:
     """x: (...,); ks: (s, ...); coefs: (m, s); base_scale: (m,); h: scalar.
 
     Returns (m,) + x.shape; out[r] = base_scale[r]*x + h*sum_i coefs[r,i]*ks[i].
@@ -121,15 +128,15 @@ def butcher_combine_rows_pallas(x: jnp.ndarray, ks: jnp.ndarray,
     xf, kf, rows_pad, n = _pad_to_tiles(x, ks, block_rows)
     acc_dt = jnp.promote_types(x.dtype, jnp.float32)
     hc = (h * coefs).astype(acc_dt)
-    sc = base_scale.astype(acc_dt)
+    sc = base_scale.astype(acc_dt).reshape(1, m)
 
     grid = (rows_pad // block_rows,)
     out = pl.pallas_call(
         functools.partial(_rows_kernel, s=s, m=m, acc_dt=acc_dt),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((m, s), lambda r: (0, 0)),              # coefs
-            pl.BlockSpec((m,), lambda r: (0,)),                  # base_scale
+            pl.BlockSpec(memory_space=pltpu.SMEM),               # coefs
+            pl.BlockSpec(memory_space=pltpu.SMEM),               # base_scale
             pl.BlockSpec((block_rows, LANE), lambda r: (r, 0)),  # x tile
             pl.BlockSpec((s, block_rows, LANE),
                          lambda r: (0, r, 0)),                   # ks tile

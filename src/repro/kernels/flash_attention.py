@@ -98,7 +98,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            scale: Optional[float] = None,
                            block_q: int = DEFAULT_BLOCK_Q,
                            block_k: int = DEFAULT_BLOCK_K,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """q: (B, H, Sq, D); k, v: (B, Hkv, Sk, D). Returns (B, H, Sq, D)."""
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape

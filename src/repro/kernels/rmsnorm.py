@@ -39,7 +39,7 @@ def _kernel_res(x_ref, res_ref, w_ref, o_ref, *, eps: float):
 def rms_norm_pallas(x: jnp.ndarray, weight: jnp.ndarray,
                     residual: Optional[jnp.ndarray] = None,
                     *, eps: float = 1e-6, block_rows: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     orig_shape = x.shape
     d = x.shape[-1]
     rows = x.size // d

@@ -9,17 +9,7 @@ from __future__ import annotations
 import math
 
 import jax
-
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    jax.sharding.AxisType) only exist in newer releases; Auto is the
-    default there, so omitting the kwarg on older jax is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,7 +20,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2):
@@ -52,7 +43,8 @@ def make_debug_mesh(n_data: int = 2, n_model: int = 2):
             "backend.  Tests should use the run_sharded fixture from "
             "tests/conftest.py, which spawns a fresh subprocess with the "
             "flag set.")
-    return make_mesh_compat((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_lane_mesh(shape, axes=None):
@@ -78,4 +70,5 @@ def make_lane_mesh(shape, axes=None):
             f'"--xla_force_host_platform_device_count={need}" BEFORE jax '
             "initializes (tests: use the run_sharded fixture in "
             "tests/conftest.py).")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

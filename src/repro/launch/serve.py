@@ -10,6 +10,10 @@
 The bare legacy form (no subcommand) still routes to ``lm``:
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke
+
+``main(["ode", ...])`` returns the run's record (the engine, the requests
+and their results, and the field that defines them), so an in-process
+caller such as ``chip_smoke.py`` can check every result.
 """
 from __future__ import annotations
 
@@ -144,8 +148,13 @@ def _ode_main(argv=None):
               "w2": jax.random.normal(k[2], (hidden, dim)) * 0.4,
               "b2": jax.random.normal(k[3], (dim,)) * 0.1}
 
+    # The stream asks for rtol down to 1e-6.  A TPU's default f32 matmul
+    # rounds its operands to bf16, and that noise in f puts a floor under
+    # the step's error estimate: a third of the requests then never meet
+    # their tolerance.  "highest" keeps the field in f32 on every backend.
     def field(x, t, p):
-        return jnp.tanh(x @ p["w1"] + p["b1"]) @ p["w2"] + p["b2"]
+        h = jnp.tanh(jnp.dot(x, p["w1"], precision="highest") + p["b1"])
+        return jnp.dot(h, p["w2"], precision="highest") + p["b2"]
 
     cfg = AdaptiveConfig(rtol=1e-4, atol=1e-6, max_steps=args.max_steps,
                          initial_step=0.02)
@@ -182,6 +191,9 @@ def _ode_main(argv=None):
         print(f"[serve ode] naive sequential: {len(reqs)} requests in "
               f"{wall_n:.2f}s -> {len(reqs)/wall_n:.1f} req/s; per-solve "
               f"p50 {np.percentile(lats, 50)*1e3:.1f} ms")
+    return {"engine": engine, "requests": reqs, "results": results,
+            "field": field, "params": params, "cfg": cfg,
+            "tableau": get_tableau(args.method)}
 
 
 def main(argv=None):
@@ -193,4 +205,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

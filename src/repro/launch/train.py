@@ -19,12 +19,14 @@ loss/grad-norm trajectory is BIT-identical to an uninterrupted run.
 leaves a complete prefix) — the harness and the CI train-smoke lane diff
 these files across kill/resume boundaries (tools/check_resume_divergence.py).
 
-Runs on whatever devices exist — a 1-CPU test, a 256-chip pod, or the
-512-chip multi-pod mesh (``--mesh``), resharding the checkpoint onto the
-current topology (elastic restart).  Real-TPU deployment flags
-(latency-hiding scheduler for collective/compute overlap, async
-collectives) are appended to XLA_FLAGS when --tpu-flags is passed; they are
-no-ops on CPU.
+Runs on whatever devices exist — a 1-CPU test, one TPU chip, a 256-chip
+pod, or the 512-chip multi-pod mesh (``--mesh``), resharding the
+checkpoint onto the current topology (elastic restart).
+
+``main(argv)`` returns the run's record (per-step losses and seconds, the
+jitted step, the final state and the last batch), so an in-process caller
+such as ``chip_smoke.py`` can check the losses and lower the very step
+function the loop ran.
 """
 from __future__ import annotations
 
@@ -33,14 +35,6 @@ import json
 import os
 import sys
 import time
-
-TPU_FLAGS = (
-    " --xla_tpu_enable_data_parallel_all_reduce_opt=true"
-    " --xla_tpu_data_parallel_opt_different_sized_ops=true"
-    " --xla_enable_async_collective_permute=true"
-    " --xla_tpu_overlap_compute_collective_tc=true"
-)
-
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
@@ -75,16 +69,12 @@ def main(argv=None):
                     "grad_norm/lr), flushed — for resume-divergence checks")
     ap.add_argument("--mesh", default="none",
                     choices=["none", "pod", "multipod", "debug"])
-    ap.add_argument("--tpu-flags", action="store_true")
     ap.add_argument("--fail-at-step", type=int, default=-1,
                     help="inject a failure (fault-tolerance demo)")
     ap.add_argument("--step-delay-s", type=float, default=0.0,
                     help="sleep after each step — paces the loop so the "
                     "fault harness can SIGKILL mid-epoch deterministically")
     args = ap.parse_args(argv)
-
-    if args.tpu_flags:
-        os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + TPU_FLAGS
 
     import jax
 
@@ -175,6 +165,7 @@ def main(argv=None):
 
     t0 = time.time()
     epoch_losses = []
+    losses, step_seconds, batch = [], [], None
     for step in range(start_step, total_steps):
         batch = next(pipe)
         if arch.encdec:
@@ -195,10 +186,13 @@ def main(argv=None):
         def on_failure():
             print(f"[train] step {step} failed; state intact, retrying")
 
+        t_step = time.perf_counter()
         state, metrics = run_with_retries(do_step, RetryConfig(),
                                           on_failure)
         epoch = step // steps_per_epoch
-        loss = float(metrics["loss"])
+        loss = float(metrics["loss"])          # waits for the step
+        step_seconds.append(time.perf_counter() - t_step)
+        losses.append(loss)
         epoch_losses.append(loss)
         if metrics_f is not None:
             # json round-trips python floats exactly (repr-based), so the
@@ -231,7 +225,11 @@ def main(argv=None):
         metrics_f.close()
     sstats = jax.tree_util.tree_map(int, state["solver_stats"])
     print(f"[train] done (solver stats {sstats})")
+    return {"losses": losses, "step_seconds": step_seconds,
+            "step_fn": step_fn, "state": state, "batch": batch}
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -19,7 +19,7 @@ axes with ``shard_map``.  The shape of the subsystem:
   the structurally-zero time cotangents) over the lane axes.  That
   contract is asserted jaxpr-level by ``repro.analysis``'s
   ``collective-count`` probe (docs/parallel.md).
-* ``check_rep=False`` throughout: the adaptive driver is a
+* ``check_vma=False`` throughout: the adaptive driver is a
   ``lax.while_loop`` and shard_map has no replication rule for it.
 
 The gradient path additionally requires every custom_vjp driver to expose
@@ -33,7 +33,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.stepper import BatchedAdaptiveSolution, SolverState
@@ -193,11 +192,11 @@ def sharded_solve_triple(body, mesh, axes: Sequence[str], x0, params, *,
     """
     lane = lane_spec(axes)
     pspec = P() if params_spec is None else params_spec
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(lane, pspec),
         out_specs=(lane_spec(axes, ys_lane_axis), lane, lane),
-        check_rep=False)(x0, params)
+        check_vma=False)(x0, params)
 
 
 def with_shard_load_stats(stats: dict, n_shards: int) -> dict:

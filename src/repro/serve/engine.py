@@ -377,6 +377,11 @@ class SolveEngine:
         return results
 
     @property
+    def compiled_advance(self) -> Dict[int, Any]:
+        """{bucket: the AOT-compiled ``advance`` executable} (read-only)."""
+        return dict(self._advance)
+
+    @property
     def stats(self) -> Dict[str, int]:
         return {"steps_total": self._steps_total,
                 "lanes": self._lanes,

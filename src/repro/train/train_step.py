@@ -85,6 +85,19 @@ def _forward_loss(params, batch, arch: ArchConfig, shard,
     return loss + out["aux"], loss
 
 
+def make_grad_fn(arch: ArchConfig, tcfg: TrainConfig, shard=no_shard):
+    """``(params, batch) -> (grads, total_loss, ce_loss)`` for one batch:
+    the gradient a train step feeds its optimizer, under the arch's
+    gradient strategy."""
+    def grads_of(params, batch):
+        def lf(p):
+            return _forward_loss(p, batch, arch, shard, tcfg.loss_chunk)
+        (total, ce), grads = jax.value_and_grad(lf, has_aux=True)(params)
+        return grads, total, ce
+
+    return grads_of
+
+
 def make_train_step(arch: ArchConfig, tcfg: TrainConfig,
                     lr_fn: Optional[Callable] = None, shard=no_shard,
                     grad_constraint: Optional[Callable] = None):
@@ -97,11 +110,7 @@ def make_train_step(arch: ArchConfig, tcfg: TrainConfig,
     if lr_fn is None:
         lr_fn = lambda step: jnp.asarray(tcfg.lr, jnp.float32)  # noqa: E731
 
-    def grads_of(params, batch):
-        def lf(p):
-            return _forward_loss(p, batch, arch, shard, tcfg.loss_chunk)
-        (total, ce), grads = jax.value_and_grad(lf, has_aux=True)(params)
-        return grads, total, ce
+    grads_of = make_grad_fn(arch, tcfg, shard)
 
     # static forward-solve cost of one train step (NODE archs; see state.py)
     solve_steps, solve_fevals = node_solver_counts(arch)

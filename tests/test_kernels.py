@@ -152,3 +152,103 @@ def test_flash_attention_swa_equals_full_when_window_covers():
                                block_q=32, block_k=32, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
                                atol=1e-6)
+
+
+# --- reverse mode through the Pallas branches of ops ------------------------
+#
+# ops.rms_norm / ops.attention wrap the kernels in custom VJPs whose backward
+# is the VJP of the jnp oracle.  Off the TPU, use_pallas=True runs the kernel
+# forward in interpret mode, so these gradients go through the same
+# custom_vjp the chip runs.
+
+def _grads(f, args, cot_key):
+    out = f(*args)
+    cot = _rand(cot_key, out.shape, jnp.float32)
+    return jax.grad(lambda *a: jnp.sum(f(*a) * cot),
+                    argnums=tuple(range(len(args))))(*args)
+
+
+def _assert_trees_close(got, want, **tol):
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), **tol)
+
+
+@pytest.mark.parametrize("with_residual", [False, True])
+def test_rms_norm_pallas_grad_matches_ref(with_residual):
+    from repro.kernels import ops
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = _rand(keys[0], (3, 40, 128), jnp.float32)
+    w = _rand(keys[1], (128,), jnp.float32)
+    args = (x, w) + ((_rand(keys[2], x.shape, jnp.float32),)
+                     if with_residual else ())
+    got = _grads(lambda *a: ops.rms_norm(*a, eps=1e-5, use_pallas=True),
+                 args, keys[3])
+    want = _grads(lambda *a: ref.rms_norm_ref(*a, eps=1e-5), args, keys[3])
+    assert len(got) == len(args)
+    _assert_trees_close(got, want, rtol=1e-5, atol=1e-5)
+
+
+GRAD_ATTN_CASES = {
+    # (B, H, Hkv, Sq, Sk, D, causal, window, q_offset)
+    "causal": (1, 2, 2, 64, 64, 32, True, None, 0),
+    "gqa": (2, 4, 2, 64, 64, 32, True, None, 0),
+    "sliding_window": (1, 4, 1, 96, 96, 32, True, 24, 0),
+    "q_offset": (1, 4, 2, 32, 96, 32, True, None, 64),
+    "non_causal": (1, 2, 1, 48, 80, 32, False, None, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(GRAD_ATTN_CASES))
+def test_attention_pallas_grad_matches_ref(case):
+    from repro.kernels import ops
+    B, H, Hkv, Sq, Sk, D, causal, window, q_offset = GRAD_ATTN_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = _rand(keys[0], (B, H, Sq, D), jnp.float32)
+    k = _rand(keys[1], (B, Hkv, Sk, D), jnp.float32)
+    v = _rand(keys[2], (B, Hkv, Sk, D), jnp.float32)
+    kw = dict(causal=causal, window=window, q_offset=q_offset)
+    got = _grads(lambda *a: ops.attention(*a, use_pallas=True, **kw),
+                 (q, k, v), keys[3])
+    want = _grads(lambda *a: ref.attention_ref(*a, **kw), (q, k, v),
+                  keys[3])
+    _assert_trees_close(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_attention_pallas_vjp_saves_only_inputs():
+    """The forward of the kernel's VJP keeps (q, k, v), never the scores."""
+    from repro.kernels import ops
+    q = jnp.ones((1, 2, 64, 32))
+    k = v = jnp.ones((1, 1, 64, 32))
+    _, vjp = jax.vjp(lambda *a: ops.attention(*a, use_pallas=True), q, k, v)
+    sizes = sorted(leaf.size for leaf in jax.tree_util.tree_leaves(vjp))
+    assert max(sizes) <= q.size, sizes      # no (Sq, Sk)-sized residual
+
+
+def test_node_lm_grad_pallas_matches_jnp():
+    """A NODE-mode smoke LM trained with symplectic gradients: the kernel
+    path (Pallas norm, attention and stage combine, interpret mode here)
+    gives the gradient of the jnp path."""
+    from repro.configs import get_smoke_arch
+    from repro.configs.base import NodeConfig
+    from repro.data.tokens import synthetic_lm_batch
+    from repro.train import TrainConfig, init_train_state, make_grad_fn
+
+    base = get_smoke_arch("qwen3-0.6b")
+    tcfg = TrainConfig()
+    params = init_train_state(jax.random.PRNGKey(0), base, tcfg).params
+    batch = {k: jnp.asarray(a) for k, a in
+             synthetic_lm_batch(0, 2, 17, base.vocab).items()}
+
+    def grads(use_pallas, backend):
+        arch = base.with_(use_pallas=use_pallas, node=NodeConfig(
+            mode="node", method="euler", grad_mode="symplectic",
+            combine_backend=backend))
+        g, loss, _ = make_grad_fn(arch, tcfg)(params, batch)
+        return g, loss
+
+    g_k, l_k = grads(True, "pallas")
+    g_j, l_j = grads(False, "jnp")
+    np.testing.assert_allclose(float(l_k), float(l_j), rtol=1e-5)
+    _assert_trees_close(g_k, g_j, rtol=1e-4, atol=1e-6)

@@ -326,7 +326,6 @@ print("PASS")
 """
 
 _GRIDS_SCRIPT = _PREAMBLE + r"""
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core.rk import rk_solve_adaptive_batched
 from repro.core.tableau import get_tableau
@@ -339,9 +338,9 @@ def drv(x0_, params_):
     return rk_solve_adaptive_batched(field, tab, x0_, 0.0, 1.0, params_,
                                      cfg)
 
-sh = jax.jit(shard_map(drv, mesh=mesh, in_specs=(P("data"), P()),
-                       out_specs=batched_solution_specs(("data",)),
-                       check_rep=False))(x0, params)
+sh = jax.jit(jax.shard_map(drv, mesh=mesh, in_specs=(P("data"), P()),
+                           out_specs=batched_solution_specs(("data",)),
+                           check_vma=False))(x0, params)
 # every field of the sharded solution -- including accepted grids (ts, hs,
 # xs) and the h carry -- is bitwise the jitted local solve of each lane
 # block (shard_map's body compiles exactly the local program)

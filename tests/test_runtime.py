@@ -158,9 +158,8 @@ def test_elastic_reshard_cpu():
     """Restoring onto a different device layout: single-device roundtrip
     via explicit shardings (the multi-chip path is the same code)."""
     from repro.runtime import reshard_state
-    from repro.launch.mesh import make_mesh_compat
-    from jax.sharding import PartitionSpec as P
-    mesh = make_mesh_compat((1,), ("data",))
+    from jax.sharding import AxisType, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     s = _state()
     specs = jax.tree_util.tree_map(lambda _: P(), s)
     out = reshard_state(s, mesh, specs)
@@ -185,3 +184,47 @@ def test_train_driver_crash_resume(tmp_path):
                     "--global-batch", "4", "--seq-len", "32",
                     "--ckpt-dir", str(tmp_path)])
     assert Checkpointer(str(tmp_path)).latest_step() == 14
+
+
+_CACHE_SCRIPT = r"""
+import json, jax, jax.numpy as jnp
+from repro.runtime.compile_cache import enable_compile_cache
+used = enable_compile_cache()
+jax.jit(lambda x: jnp.sin(x) * 2.0)(jnp.arange(8.0)).block_until_ready()
+print(json.dumps({"used": used,
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_dir(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, where set, is where the cache lands and
+    the helper sets no other; otherwise it is <checkout>/.jax_cache."""
+    import json
+    import subprocess
+    import sys
+
+    from repro.runtime.compile_cache import DEFAULT_DIR
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(DEFAULT_DIR.parent / "src"), env.get("PYTHONPATH", "")])
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+        # cache even this tiny compile, so the entry shows
+        env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+        script = _CACHE_SCRIPT
+    else:
+        # look at the setting only: no compile, so nothing lands in the
+        # checkout's own cache
+        script = _CACHE_SCRIPT.replace(
+            "jax.jit(lambda x: jnp.sin(x) * 2.0)(jnp.arange(8.0))"
+            ".block_until_ready()", "")
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    want = str(tmp_path) if env_dir else str(DEFAULT_DIR)
+    assert out == {"used": want, "config": want}
+    if env_dir:
+        assert any(tmp_path.iterdir())
