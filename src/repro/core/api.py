@@ -82,6 +82,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import scopes
 from .adjoint import (odeint_adjoint, odeint_adjoint_adaptive,
                       odeint_adjoint_adaptive_batched)
 from .backprop import odeint_backprop, odeint_remat_solve, odeint_remat_step
@@ -805,79 +806,84 @@ def solve(f: VectorField, x0, params, *,
                  default), ``"auto"`` (``repro.parallel`` path rules), or
                  an explicit ``PartitionSpec`` pytree/prefix.
     """
-    tab = get_tableau(method) if isinstance(method, str) else method
-    resolve_backend(backend)  # eager validation, single source
-    gradient = as_gradient("symplectic" if gradient is None else gradient)
-    saveat = SaveAt(t1=1.0) if saveat is None else saveat
-    if batch_axis is not None and batch_axis != 0:
-        raise ValueError(
-            f"batch_axis={batch_axis!r}: only the leading axis "
-            "(batch_axis=0) is supported — move the trajectory axis of "
-            "every state leaf to axis 0")
-    batched = batch_axis is not None
-    lanes = lane_count(x0) if batched else None
-
-    if isinstance(stepping, AdaptiveConfig):
-        stepping_kind, n_steps, adaptive = "adaptive", None, stepping
-    elif isinstance(stepping, (int, np.integer)) \
-            and not isinstance(stepping, bool):
-        if stepping < 1:
+    with jax.named_scope(scopes.ODE_SOLVE):
+        tab = get_tableau(method) if isinstance(method, str) else method
+        resolve_backend(backend)  # eager validation, single source
+        gradient = as_gradient("symplectic" if gradient is None else gradient)
+        saveat = SaveAt(t1=1.0) if saveat is None else saveat
+        if batch_axis is not None and batch_axis != 0:
             raise ValueError(
-                f"stepping={stepping}: a fixed-grid solve needs >= 1 steps")
-        stepping_kind, n_steps, adaptive = "fixed", int(stepping), None
-    else:
-        raise TypeError(
-            "stepping must be an int (fixed-grid step count) or an "
-            f"AdaptiveConfig; got {type(stepping).__name__}")
+                f"batch_axis={batch_axis!r}: only the leading axis "
+                "(batch_axis=0) is supported — move the trajectory axis of "
+                "every state leaf to axis 0")
+        batched = batch_axis is not None
+        lanes = lane_count(x0) if batched else None
 
-    _check_capability(gradient, stepping_kind, saveat.kind, batched)
-    t0 = jnp.asarray(t0, dtype=jnp.result_type(float))
-    ctx = _Ctx(f, tab, n_steps, adaptive, backend)
-
-    if mesh is None and sharding is not None:
-        raise ValueError("solve(sharding=...) requires mesh=: the params "
-                         "placement only means something on a mesh")
-    if mesh is not None:
-        if not batched:
-            raise ValueError(
-                "solve(mesh=...) shards the lane axis over the mesh's data "
-                "axes: pass batch_axis=0 (a single trajectory has no lane "
-                "axis to shard — see docs/parallel.md)")
-        return _solve_sharded(gradient, ctx, tab, n_steps, stepping_kind,
-                              saveat, x0, t0, params, lanes, mesh, sharding)
-
-    if saveat.kind == "t1":
-        t1 = jnp.asarray(saveat.t1, dtype=t0.dtype)
-        if stepping_kind == "fixed":
-            # the fixed grid is state-independent: the plain driver IS the
-            # per-lane solve, only the stats shapes change.
-            ys = gradient.fixed(ctx, x0, t0, t1, params)
-            stats, success = _fixed_stats(tab, n_steps, 1, lanes)
-        elif batched:
-            ys, stats, success = gradient.adaptive_batched_with_stats(
-                ctx, x0, t0, t1, params)
+        if isinstance(stepping, AdaptiveConfig):
+            stepping_kind, n_steps, adaptive = "adaptive", None, stepping
+        elif isinstance(stepping, (int, np.integer)) \
+                and not isinstance(stepping, bool):
+            if stepping < 1:
+                raise ValueError(f"stepping={stepping}: a fixed-grid solve "
+                                 "needs >= 1 steps")
+            stepping_kind, n_steps, adaptive = "fixed", int(stepping), None
         else:
-            ys, stats, success = gradient.adaptive_with_stats(
-                ctx, x0, t0, t1, params)
-        return Solution(ys=ys, final_state=ys, stats=stats, success=success)
+            raise TypeError(
+                "stepping must be an int (fixed-grid step count) or an "
+                f"AdaptiveConfig; got {type(stepping).__name__}")
 
-    ts = _as_ts(saveat.ts, t0.dtype, t0)
-    if saveat.kind == "ts":
-        if stepping_kind == "fixed":
-            ys = gradient.fixed_saveat(ctx, x0, t0, ts, params)
-            stats, success = _fixed_stats(tab, n_steps, ts.shape[0], lanes)
-        elif batched:
-            ys, stats, success = gradient.adaptive_saveat_batched_with_stats(
-                ctx, x0, t0, ts, params)
-        else:
-            ys, stats, success = gradient.adaptive_saveat_with_stats(
-                ctx, x0, t0, ts, params)
-    else:  # dense
-        ys, stats, success = gradient.dense_saveat_with_stats(
-            ctx, x0, t0, ts, params)
+        _check_capability(gradient, stepping_kind, saveat.kind, batched)
+        t0 = jnp.asarray(t0, dtype=jnp.result_type(float))
+        ctx = _Ctx(f, tab, n_steps, adaptive, backend)
 
-    final = jax.tree_util.tree_map(lambda l: l[-1], ys)
-    return Solution(ys=ys, final_state=final, stats=stats, success=success)
+        if mesh is None and sharding is not None:
+            raise ValueError("solve(sharding=...) requires mesh=: the params "
+                             "placement only means something on a mesh")
+        if mesh is not None:
+            if not batched:
+                raise ValueError(
+                    "solve(mesh=...) shards the lane axis over the mesh's "
+                    "data axes: pass batch_axis=0 (a single trajectory has "
+                    "no lane axis to shard — see docs/parallel.md)")
+            return _solve_sharded(gradient, ctx, tab, n_steps,
+                                  stepping_kind, saveat, x0, t0, params,
+                                  lanes, mesh, sharding)
+
+        if saveat.kind == "t1":
+            t1 = jnp.asarray(saveat.t1, dtype=t0.dtype)
+            if stepping_kind == "fixed":
+                # the fixed grid is state-independent: the plain driver IS
+                # the per-lane solve, only the stats shapes change.
+                ys = gradient.fixed(ctx, x0, t0, t1, params)
+                stats, success = _fixed_stats(tab, n_steps, 1, lanes)
+            elif batched:
+                ys, stats, success = gradient.adaptive_batched_with_stats(
+                    ctx, x0, t0, t1, params)
+            else:
+                ys, stats, success = gradient.adaptive_with_stats(
+                    ctx, x0, t0, t1, params)
+            return Solution(ys=ys, final_state=ys, stats=stats,
+                            success=success)
+
+        ts = _as_ts(saveat.ts, t0.dtype, t0)
+        if saveat.kind == "ts":
+            if stepping_kind == "fixed":
+                ys = gradient.fixed_saveat(ctx, x0, t0, ts, params)
+                stats, success = _fixed_stats(tab, n_steps, ts.shape[0],
+                                              lanes)
+            elif batched:
+                ys, stats, success = \
+                    gradient.adaptive_saveat_batched_with_stats(
+                        ctx, x0, t0, ts, params)
+            else:
+                ys, stats, success = gradient.adaptive_saveat_with_stats(
+                    ctx, x0, t0, ts, params)
+        else:  # dense
+            ys, stats, success = gradient.dense_saveat_with_stats(
+                ctx, x0, t0, ts, params)
+
+        final = jax.tree_util.tree_map(lambda l: l[-1], ys)
+        return Solution(ys=ys, final_state=final, stats=stats, success=success)
 
 
 def _solve_sharded(gradient: GradientStrategy, ctx: _Ctx,

@@ -42,6 +42,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from ..runtime import scopes
 from .combine import StageCombiner, alloc_stages, get_combiner, set_stage
 from .rk import (AdaptiveConfig, VectorField, apply_on_failure,
                  apply_on_failure_lanes, lane_bcast, rk_solve_adaptive,
@@ -58,6 +59,12 @@ Pytree = Any
 
 def _tree_add(a, b):
     return jax.tree_util.tree_map(jnp.add, a, b)
+
+
+def _accumulate(gtheta, gstep):
+    """The parameter gradient's running sum over the backward steps."""
+    with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
+        return _tree_add(gtheta, gstep)
 
 
 def _tree_zeros(t):
@@ -100,15 +107,18 @@ def symplectic_step_adjoint(f: VectorField, tab: ButcherTableau,
         l_i = jax.tree_util.tree_map(jnp.negative, xbar)
         L = set_stage(L, i, l_i)
         bt_i = btilde(i)
-        contrib = jax.tree_util.tree_map(
-            lambda g: jnp.asarray(bt_i, dtype=g.dtype) * g, thbar)
-        gtheta = contrib if gtheta is None else _tree_add(gtheta, contrib)
+        with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
+            contrib = jax.tree_util.tree_map(
+                lambda g: jnp.asarray(bt_i, dtype=g.dtype) * g, thbar)
+            gtheta = contrib if gtheta is None else _tree_add(gtheta,
+                                                              contrib)
         dep = l_i
     # --- lambda_n = lambda_{n+1} - h sum_i btilde_i l_{n,i} --------------
     lam_n = combiner.lambda_update(lam_next, L, h)
     # grad_theta step contribution: + h sum_i btilde_i (df/dtheta)^T Lambda_i
-    gtheta = jax.tree_util.tree_map(
-        lambda g: jnp.asarray(h, dtype=g.dtype) * g, gtheta)
+    with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
+        gtheta = jax.tree_util.tree_map(
+            lambda g: jnp.asarray(h, dtype=g.dtype) * g, gtheta)
     return lam_n, gtheta
 
 
@@ -153,7 +163,7 @@ def _sym_bwd(f, tab, n_steps, combine_backend, res, lam_N):
         x_n, t_n = inputs
         lam, gstep = symplectic_step_adjoint(f, tab, x_n, t_n, h, params,
                                              lam, combiner)
-        return (lam, _tree_add(gtheta, gstep)), None
+        return (lam, _accumulate(gtheta, gstep)), None
 
     (lam0, gtheta), _ = jax.lax.scan(body, (lam_N, _tree_zeros(params)),
                                      (xs, ts), reverse=True)
@@ -205,7 +215,7 @@ def _syma_bwd(f, tab, cfg, combine_backend, res, lam_N):
         def live(_):
             lam2, gstep = symplectic_step_adjoint(
                 f, tab, x_n, t_n, h_n, params, lam, combiner)
-            return lam2, _tree_add(gtheta, gstep)
+            return lam2, _accumulate(gtheta, gstep)
 
         def dead(_):
             return lam, gtheta
@@ -299,7 +309,7 @@ def _sym_saveat_bwd(f, tab, n_steps, combine_backend, res, obs_bar):
             x_n, t_n = inputs
             lam_c, gstep = symplectic_step_adjoint(
                 f, tab, x_n, t_n, h_seg, params, lam_c, combiner)
-            return (lam_c, _tree_add(g_c, gstep)), None
+            return (lam_c, _accumulate(g_c, gstep)), None
 
         (lam, gtheta), _ = jax.lax.scan(body, (lam, gtheta),
                                         (seg_xs, seg_ts), reverse=True)
@@ -371,7 +381,7 @@ def _syma_saveat_bwd(f, tab, cfg, combine_backend, res, obs_bar):
             def live(_):
                 lam2, gstep = symplectic_step_adjoint(
                     f, tab, x_n, t_n, h_n, params, lam_c, combiner)
-                return lam2, _tree_add(g_c, gstep)
+                return lam2, _accumulate(g_c, gstep)
 
             def dead(_):
                 return lam_c, g_c
@@ -454,18 +464,21 @@ def symplectic_step_adjoint_lanes(f: VectorField, tab: ButcherTableau,
         xbar, thbar = jax.vmap(stage_vjp)(Xi, t_n + c[i] * h_n, Lam_i)
         l_i = jax.tree_util.tree_map(jnp.negative, xbar)
         L = set_stage(L, i, l_i)
-        if b[i] == 0.0:  # Eq. (8): btilde_i = h_n, per lane
-            contrib = jax.tree_util.tree_map(
-                lambda g: lane_bcast(h_n, g).astype(g.dtype) * g, thbar)
-        else:
-            contrib = jax.tree_util.tree_map(
-                lambda g: jnp.asarray(b[i], dtype=g.dtype) * g, thbar)
-        gtheta = contrib if gtheta is None else _tree_add(gtheta, contrib)
+        with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
+            if b[i] == 0.0:  # Eq. (8): btilde_i = h_n, per lane
+                contrib = jax.tree_util.tree_map(
+                    lambda g: lane_bcast(h_n, g).astype(g.dtype) * g, thbar)
+            else:
+                contrib = jax.tree_util.tree_map(
+                    lambda g: jnp.asarray(b[i], dtype=g.dtype) * g, thbar)
+            gtheta = contrib if gtheta is None else _tree_add(gtheta,
+                                                              contrib)
         dep = l_i
     lam_n = jax.vmap(combiner.lambda_update,
                      in_axes=(0, 1, 0))(lam_next, L, h_n)
-    gtheta = jax.tree_util.tree_map(
-        lambda g: lane_bcast(h_n, g).astype(g.dtype) * g, gtheta)
+    with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
+        gtheta = jax.tree_util.tree_map(
+            lambda g: lane_bcast(h_n, g).astype(g.dtype) * g, gtheta)
     return lam_n, gtheta
 
 
@@ -489,11 +502,12 @@ def _masked_lanes_alg2_scan(f, tab, combiner, params, max_steps,
             lam = jax.tree_util.tree_map(
                 lambda a, b: jnp.where(lane_bcast(valid, a), b, a),
                 lam, lam2)
-            gsum = jax.tree_util.tree_map(
-                lambda g: jnp.sum(jnp.where(lane_bcast(valid, g), g,
-                                            jnp.zeros((), g.dtype)),
-                                  axis=0), gstep)
-            return lam, _tree_add(gtheta, gsum)
+            with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
+                gsum = jax.tree_util.tree_map(
+                    lambda g: jnp.sum(jnp.where(lane_bcast(valid, g), g,
+                                                jnp.zeros((), g.dtype)),
+                                      axis=0), gstep)
+            return lam, _accumulate(gtheta, gsum)
 
         def dead(args):
             return args

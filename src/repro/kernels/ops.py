@@ -24,6 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..runtime import scopes
 from . import ref
 from .butcher_combine import (butcher_combine_pallas,
                               butcher_combine_rows_pallas)
@@ -122,6 +123,8 @@ def attention(q, k, v, *, causal: bool = True,
               window: Optional[int] = None, q_offset: int = 0,
               scale: Optional[float] = None,
               use_pallas: Optional[bool] = None):
-    if _resolve(use_pallas):
-        return _attention_kernel(q, k, v, causal, window, q_offset, scale)
-    return _attention_jnp(q, k, v, causal, window, q_offset, scale)
+    with jax.named_scope(scopes.ATTENTION):
+        if _resolve(use_pallas):
+            return _attention_kernel(q, k, v, causal, window, q_offset,
+                                     scale)
+        return _attention_jnp(q, k, v, causal, window, q_offset, scale)

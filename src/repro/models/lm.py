@@ -29,6 +29,7 @@ from repro.configs.base import ArchConfig
 from repro.core import SaveAt, as_gradient, solve
 from repro.nn.common import dense_init, embed_init, no_shard, split_keys
 from repro.nn.norm import init_rmsnorm, rmsnorm
+from repro.runtime import scopes
 from .blocks import init_layer, init_layer_cache, layer_forward
 
 
@@ -139,10 +140,11 @@ def _embed(params, cfg, tokens, extra_embeds, shard):
 
 
 def _head_parts(params, cfg, x):
-    x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps,
-                use_pallas=cfg.use_pallas)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return x, head
+    with jax.named_scope(scopes.LM_LOSS):
+        x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps,
+                    use_pallas=cfg.use_pallas)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        return x, head
 
 
 def _head(params, cfg, x, shard):

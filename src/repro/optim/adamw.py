@@ -13,6 +13,8 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import scopes
+
 
 @dataclasses.dataclass(frozen=True)
 class AdamWConfig:
@@ -42,41 +44,42 @@ def adamw_init(params, cfg: AdamWConfig):
 
 
 def adamw_update(params, grads, state, lr, cfg: AdamWConfig):
-    step = state["step"] + 1
-    b1c = 1.0 - cfg.b1 ** step.astype(jnp.float32)
-    b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
+    with jax.named_scope(scopes.OPTIMIZER):
+        step = state["step"] + 1
+        b1c = 1.0 - cfg.b1 ** step.astype(jnp.float32)
+        b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v, master):
-        g32 = g.astype(jnp.float32)
-        m = cfg.b1 * m + (1 - cfg.b1) * g32
-        v = cfg.b2 * v + (1 - cfg.b2) * g32 * g32
-        mh = m / b1c
-        vh = v / b2c
-        base = master if master is not None else p.astype(jnp.float32)
-        new = base - lr * (mh / (jnp.sqrt(vh) + cfg.eps)
-                           + cfg.weight_decay * base)
-        return new.astype(p.dtype), m, v, new
+        def upd(p, g, m, v, master):
+            g32 = g.astype(jnp.float32)
+            m = cfg.b1 * m + (1 - cfg.b1) * g32
+            v = cfg.b2 * v + (1 - cfg.b2) * g32 * g32
+            mh = m / b1c
+            vh = v / b2c
+            base = master if master is not None else p.astype(jnp.float32)
+            new = base - lr * (mh / (jnp.sqrt(vh) + cfg.eps)
+                               + cfg.weight_decay * base)
+            return new.astype(p.dtype), m, v, new
 
-    leaves_p, treedef = jax.tree_util.tree_flatten(params)
-    leaves_g = jax.tree_util.tree_leaves(grads)
-    leaves_m = jax.tree_util.tree_leaves(state["m"])
-    leaves_v = jax.tree_util.tree_leaves(state["v"])
-    if "master" in state:
-        leaves_w = jax.tree_util.tree_leaves(state["master"])
-    else:
-        leaves_w = [None] * len(leaves_p)
+        leaves_p, treedef = jax.tree_util.tree_flatten(params)
+        leaves_g = jax.tree_util.tree_leaves(grads)
+        leaves_m = jax.tree_util.tree_leaves(state["m"])
+        leaves_v = jax.tree_util.tree_leaves(state["v"])
+        if "master" in state:
+            leaves_w = jax.tree_util.tree_leaves(state["master"])
+        else:
+            leaves_w = [None] * len(leaves_p)
 
-    np_, nm, nv, nw = [], [], [], []
-    for p, g, m, v, w in zip(leaves_p, leaves_g, leaves_m, leaves_v,
-                             leaves_w):
-        a, b, c, d = upd(p, g, m, v, w)
-        np_.append(a)
-        nm.append(b)
-        nv.append(c)
-        nw.append(d)
+        np_, nm, nv, nw = [], [], [], []
+        for p, g, m, v, w in zip(leaves_p, leaves_g, leaves_m, leaves_v,
+                                 leaves_w):
+            a, b, c, d = upd(p, g, m, v, w)
+            np_.append(a)
+            nm.append(b)
+            nv.append(c)
+            nw.append(d)
 
-    unf = treedef.unflatten
-    new_state = {"m": unf(nm), "v": unf(nv), "step": step}
-    if "master" in state:
-        new_state["master"] = unf(nw)
-    return unf(np_), new_state
+        unf = treedef.unflatten
+        new_state = {"m": unf(nm), "v": unf(nv), "step": step}
+        if "master" in state:
+            new_state["master"] = unf(nw)
+        return unf(np_), new_state

@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import scopes
+
 
 def global_norm(tree):
     leaves = jax.tree_util.tree_leaves(tree)
@@ -12,8 +14,9 @@ def global_norm(tree):
 
 
 def clip_by_global_norm(grads, max_norm: float):
-    norm = global_norm(grads)
-    scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
-    return jax.tree_util.tree_map(
-        lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype), grads), \
-        norm
+    with jax.named_scope(scopes.OPTIMIZER):
+        norm = global_norm(grads)
+        scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
+        return jax.tree_util.tree_map(
+            lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype),
+            grads), norm
