@@ -19,7 +19,7 @@ from .api import (GRADIENT_REGISTRY, STEPPING_KINDS, SAVEAT_KINDS,
                   mesh_capability_matrix, register_gradient, solve)
 from .odeint import GRAD_MODES, TS_MODES, odeint, odeint_with_stats
 from .rk import (ON_FAILURE_POLICIES, AdaptiveConfig, AdaptiveSolution,
-                 BatchedAdaptiveSolution, apply_on_failure,
+                 BatchedAdaptiveSolution, SlicedField, apply_on_failure,
                  apply_on_failure_lanes, hermite_observe, lane_count,
                  rk_solve_adaptive, rk_solve_adaptive_batched,
                  rk_solve_adaptive_batched_saveat_stacked,
@@ -55,6 +55,7 @@ __all__ = [
     "rk_solve_adaptive_saveat", "rk_solve_adaptive_saveat_stacked",
     "rk_solve_adaptive_batched_saveat_stacked", "lane_count",
     "rk_step", "rk_stages", "tree_scale_add", "apply_on_failure",
+    "SlicedField",
     "apply_on_failure_lanes",
     "SolverState", "FixedSolverState", "AdaptiveStepper", "FixedStepper",
     "hermite_observe", "odeint_symplectic", "odeint_symplectic_adaptive",

@@ -38,6 +38,9 @@ f(x_{n+1}), one whole network evaluation — per step.
 """
 from __future__ import annotations
 
+import dataclasses
+from typing import Callable
+
 import jax
 import jax.numpy as jnp
 
@@ -48,6 +51,33 @@ from .stepper import (  # noqa: F401  (re-exports: the step-level surface)
     BatchedAdaptiveSolution, FixedSolution, FixedSolverState, FixedStepper,
     Pytree, SolverState, VectorField, _error_norm, _error_norm_lanes,
     _time_resolution, lane_bcast, lane_count, rk_stages, rk_step)
+
+
+@dataclasses.dataclass(frozen=True)
+class SlicedField:
+    """A vector field that reads ONE leading-axis slice of its parameters.
+
+    ``index(t)`` is the int32 slice the field reads at time ``t`` and
+    ``apply(x, t, slice_params)`` the field on that slice.  Called as
+    ``f(x, t, params)`` it is the plain field: ``apply`` on
+    ``params[index(t)]`` (per leaf), so every driver that calls it as a
+    ``VectorField`` runs the same program as for an undeclared field.  The
+    symplectic backward (core/symplectic.py) reads the declaration: each
+    stage's VJP is taken with respect to the one slice, and that slice is
+    added into the parameter gradient in place.
+    """
+    index: Callable[[jnp.ndarray], jnp.ndarray]
+    apply: VectorField
+
+    def __call__(self, x, t, params):
+        return self.apply(x, t, take_slice(params, self.index(t)))
+
+
+def take_slice(params: Pytree, k) -> Pytree:
+    """``params[k]`` along the leading axis of every leaf."""
+    return jax.tree_util.tree_map(
+        lambda l: jax.lax.dynamic_index_in_dim(l, k, 0, keepdims=False),
+        params)
 
 
 def time_zero_cotangent(t):

@@ -33,6 +33,20 @@ residuals are forced to be live one-at-a-time by threading the previous
 adjoint slope through ``lax.optimization_barrier`` into the stage state, so
 neither CSE nor the scheduler can hoist all s recomputation graphs at once.
 Live memory is O(N + s + L), not O(N * s * L).
+
+Fields that read one slice of their parameters: a ``SlicedField``
+(core/rk.py) declares the leading-axis slice ``index(t)`` it reads and the
+field ``apply`` on that slice, as the NODE-mode LM's depth field does (one
+layer of the stacked units per time).  ``symplectic_step_adjoint`` then
+takes each stage's VJP with respect to that one slice and adds
+``h btilde_i`` times its cotangent into the carried gradient at the stage's
+own index, in place: the per-step parameter cotangent is one slice, not a
+zero-filled stack with one slice set.  Every other field (the CNF, MLP
+fields, a lambda wrapping a declared field) takes the dense path: the VJP
+with respect to the whole ``params`` and a whole-tree sum per step.  The
+lane-batched step (``symplectic_step_adjoint_lanes``, whose lanes may read
+different slices) and the continuous adjoint (core/adjoint.py) always take
+the dense path.
 """
 from __future__ import annotations
 
@@ -44,12 +58,12 @@ import jax.numpy as jnp
 
 from ..runtime import scopes
 from .combine import StageCombiner, alloc_stages, get_combiner, set_stage
-from .rk import (AdaptiveConfig, VectorField, apply_on_failure,
+from .rk import (AdaptiveConfig, SlicedField, VectorField, apply_on_failure,
                  apply_on_failure_lanes, lane_bcast, rk_solve_adaptive,
                  rk_solve_adaptive_batched,
                  rk_solve_adaptive_batched_saveat_stacked,
                  rk_solve_adaptive_saveat_stacked, rk_solve_fixed, rk_stages,
-                 segment_starts, time_lift as _lift,
+                 segment_starts, take_slice, time_lift as _lift,
                  time_unlift as _unlift,
                  time_zero_cotangent as _time_zero)
 from .tableau import ButcherTableau
@@ -79,13 +93,42 @@ def _barrier_with(x: Pytree, dep: Pytree) -> Pytree:
     return x_out
 
 
+def _scaled(c, tree):
+    """``c * tree`` per leaf, ``c`` cast to each leaf's dtype."""
+    return jax.tree_util.tree_map(
+        lambda g: jnp.asarray(c, dtype=g.dtype) * g, tree)
+
+
+def _add_into_slice(gtheta, k, contrib):
+    """``gtheta[k] += contrib`` per leaf: a read, an add and a write of one
+    leading-axis slice, which XLA updates in place in the carried buffer."""
+    return jax.tree_util.tree_map(
+        lambda G, g: jax.lax.dynamic_update_index_in_dim(
+            G, jax.lax.dynamic_index_in_dim(G, k, 0, keepdims=False) + g,
+            k, 0),
+        gtheta, contrib)
+
+
 def symplectic_step_adjoint(f: VectorField, tab: ButcherTableau,
-                            x_n, t_n, h, params, lam_next,
+                            x_n, t_n, h, params, lam_next, gtheta,
                             combiner: Optional[StageCombiner] = None):
-    """One backward step of Algorithm 2. Returns (lambda_n, grad_theta_step)."""
+    """One backward step of Algorithm 2.
+
+    Returns ``(lambda_n, gtheta)``: the adjoint at the step's start, and the
+    running parameter gradient ``gtheta`` with this step's
+    ``h sum_i btilde_i (df/dtheta(X_{n,i}))^T Lambda_{n,i}`` added.
+
+    A ``SlicedField`` (one leading-axis slice of ``params`` per time) takes
+    each stage's VJP with respect to the slice ``f.index(t_{n,i})`` it
+    reads and adds ``h btilde_i`` times that slice's cotangent into
+    ``gtheta`` at the stage's own index (stages may read different
+    slices).  Any other field takes the VJP with respect to the whole
+    ``params`` and adds the step's dense sum.
+    """
     combiner = combiner or get_combiner(tab)
     s = tab.s
     b, c = tab.b, tab.c
+    sliced = isinstance(f, SlicedField)
     # --- Alg.2 lines 3-7: recompute stages from the checkpoint ----------
     Xs, _K = rk_stages(f, tab, x_n, t_n, h, params, combiner)
 
@@ -94,7 +137,7 @@ def symplectic_step_adjoint(f: VectorField, tab: ButcherTableau,
         return h if b[i] == 0.0 else b[i]
 
     L = alloc_stages(s, lam_next)   # stacked adjoint slopes l_{n,i}
-    gtheta = None
+    gstep = None
     dep = lam_next  # scheduling dependency chain (see module docstring)
     for i in reversed(range(s)):
         # --- Eq. (7): Lambda_{n,i} from the slope-buffer suffix L[i+1:] --
@@ -102,23 +145,30 @@ def symplectic_step_adjoint(f: VectorField, tab: ButcherTableau,
         # --- Alg.2 lines 10-12: one VJP of one network evaluation -------
         Xi = _barrier_with(Xs[i], dep)
         t_i = t_n + c[i] * h
-        _, vjp_fn = jax.vjp(lambda X, th: f(X, t_i, th), Xi, params)
+        if sliced:
+            k = f.index(t_i)
+            _, vjp_fn = jax.vjp(lambda X, th: f.apply(X, t_i, th), Xi,
+                                take_slice(params, k))
+        else:
+            _, vjp_fn = jax.vjp(lambda X, th: f(X, t_i, th), Xi, params)
         xbar, thbar = vjp_fn(Lam_i)
         l_i = jax.tree_util.tree_map(jnp.negative, xbar)
         L = set_stage(L, i, l_i)
-        bt_i = btilde(i)
         with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
-            contrib = jax.tree_util.tree_map(
-                lambda g: jnp.asarray(bt_i, dtype=g.dtype) * g, thbar)
-            gtheta = contrib if gtheta is None else _tree_add(gtheta,
-                                                              contrib)
+            contrib = _scaled(btilde(i), thbar)
+            if sliced:
+                gtheta = _add_into_slice(gtheta, k, _scaled(h, contrib))
+            else:
+                gstep = contrib if gstep is None else _tree_add(gstep,
+                                                                contrib)
         dep = l_i
     # --- lambda_n = lambda_{n+1} - h sum_i btilde_i l_{n,i} --------------
     lam_n = combiner.lambda_update(lam_next, L, h)
-    # grad_theta step contribution: + h sum_i btilde_i (df/dtheta)^T Lambda_i
-    with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
-        gtheta = jax.tree_util.tree_map(
-            lambda g: jnp.asarray(h, dtype=g.dtype) * g, gtheta)
+    if not sliced:
+        # + h sum_i btilde_i (df/dtheta)^T Lambda_i, added whole
+        with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
+            gstep = _scaled(h, gstep)
+        gtheta = _accumulate(gtheta, gstep)
     return lam_n, gtheta
 
 
@@ -161,9 +211,8 @@ def _sym_bwd(f, tab, n_steps, combine_backend, res, lam_N):
     def body(carry, inputs):
         lam, gtheta = carry
         x_n, t_n = inputs
-        lam, gstep = symplectic_step_adjoint(f, tab, x_n, t_n, h, params,
-                                             lam, combiner)
-        return (lam, _accumulate(gtheta, gstep)), None
+        return symplectic_step_adjoint(f, tab, x_n, t_n, h, params, lam,
+                                       gtheta, combiner), None
 
     (lam0, gtheta), _ = jax.lax.scan(body, (lam_N, _tree_zeros(params)),
                                      (xs, ts), reverse=True)
@@ -213,9 +262,8 @@ def _syma_bwd(f, tab, cfg, combine_backend, res, lam_N):
         valid = idx < n_acc
 
         def live(_):
-            lam2, gstep = symplectic_step_adjoint(
-                f, tab, x_n, t_n, h_n, params, lam, combiner)
-            return lam2, _accumulate(gtheta, gstep)
+            return symplectic_step_adjoint(
+                f, tab, x_n, t_n, h_n, params, lam, gtheta, combiner)
 
         def dead(_):
             return lam, gtheta
@@ -307,9 +355,8 @@ def _sym_saveat_bwd(f, tab, n_steps, combine_backend, res, obs_bar):
         def body(carry_c, inputs):
             lam_c, g_c = carry_c
             x_n, t_n = inputs
-            lam_c, gstep = symplectic_step_adjoint(
-                f, tab, x_n, t_n, h_seg, params, lam_c, combiner)
-            return (lam_c, _accumulate(g_c, gstep)), None
+            return symplectic_step_adjoint(
+                f, tab, x_n, t_n, h_seg, params, lam_c, g_c, combiner), None
 
         (lam, gtheta), _ = jax.lax.scan(body, (lam, gtheta),
                                         (seg_xs, seg_ts), reverse=True)
@@ -379,9 +426,8 @@ def _syma_saveat_bwd(f, tab, cfg, combine_backend, res, obs_bar):
             valid = idx < n_acc
 
             def live(_):
-                lam2, gstep = symplectic_step_adjoint(
-                    f, tab, x_n, t_n, h_n, params, lam_c, combiner)
-                return lam2, _accumulate(g_c, gstep)
+                return symplectic_step_adjoint(
+                    f, tab, x_n, t_n, h_n, params, lam_c, g_c, combiner)
 
             def dead(_):
                 return lam_c, g_c
@@ -436,7 +482,9 @@ def symplectic_step_adjoint_lanes(f: VectorField, tab: ButcherTableau,
 
     Returns (lambda_n, grad_theta_step) with grad_theta_step PER LANE —
     leaves (B,) + param shape — so the caller can mask invalid lanes
-    before reducing over the batch.
+    before reducing over the batch.  Lanes may read different slices of a
+    ``SlicedField``'s parameters, so this step always takes the dense
+    path: each stage's VJP is with respect to the whole ``params``.
     """
     combiner = combiner or get_combiner(tab)
     s = tab.s

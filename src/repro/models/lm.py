@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.core import SaveAt, as_gradient, solve
+from repro.core import SaveAt, SlicedField, as_gradient, solve
 from repro.nn.common import dense_init, embed_init, no_shard, split_keys
 from repro.nn.norm import init_rmsnorm, rmsnorm
 from repro.runtime import scopes
@@ -248,20 +248,21 @@ def lm_forward(params, cfg: ArchConfig, tokens, *, caches=None, pos=None,
 
 def _depth_field(cfg: ArchConfig, shard):
     """f(x, t) = R * (unit_{floor(tR)}(x) - x): depth-time vector field
-    shared by the training solve and the depth-observation probe."""
+    shared by the training solve and the depth-observation probe.  It
+    declares the one unit it reads at time t (a ``SlicedField``), so the
+    symplectic backward differentiates and accumulates that unit alone."""
     R = cfg.n_repeats
 
-    def field(xs, t, unit_params):
-        n = jnp.clip(jnp.floor(t * R).astype(jnp.int32), 0, R - 1)
-        up = jax.tree_util.tree_map(
-            lambda l: jax.lax.dynamic_index_in_dim(l, n, 0, keepdims=False),
-            unit_params)
+    def index(t):
+        return jnp.clip(jnp.floor(t * R).astype(jnp.int32), 0, R - 1)
+
+    def apply(xs, t, up):
         y, _, _ = _unit_forward(up, xs, cfg, shard=shard)
         # the symplectic adjoint SAVES the step states {x_n}; keep them
         # sequence-sharded like the discrete-mode carries
         return shard((y - xs) * float(R), ("batch", "seq_carry", "embed"))
 
-    return field
+    return SlicedField(index, apply)
 
 
 def _node_depth_solve(params, cfg: ArchConfig, x, shard):
