@@ -7,8 +7,9 @@ For every seed and cell: the program's first steps as a run takes them
 (set-up and warm steps of the cell's job, no window), then the plain
 reference, and the numbers compared.  For the first ``--control-seeds``
 seeds also the control (the program with its precision knob one step
-below the configuration's, ``kind.CONTROL``) and each fault of
-``kind.FAULTS`` planted in the program's timed step (``kind.plant``).
+below the configuration's, ``kind.CONTROL``) and each fault the first
+cell can have (``kind.faults``) planted in the program's timed step
+(``kind.plant``).
 The cells given together share one reference, so they must differ only
 in their gradient strategy.
 
@@ -82,11 +83,11 @@ def main(argv=None) -> int:
             ctl = dataclasses.replace(cells[0], config={**cells[0].config,
                                                         **kind.CONTROL})
             extra["control"] = readings(ctl, seed, label="control")
-            for fault in kind.FAULTS:
+            for fault in kind.faults(cells[0]):
                 extra[fault] = readings(cells[0], seed, fault, fault)
         t0 = time.perf_counter()
         ref = kind.reference_readings(cells[0].config, cells[0].traffic,
-                                      seed)
+                                      seed, devices[:cells[0].chips])
         row = {"seed": seed, "ref_loss": ref["loss"],
                "reference_s": time.perf_counter() - t0}
         for name, prog in {**progs, **extra}.items():

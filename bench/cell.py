@@ -7,13 +7,14 @@ metrics.  The files behind those names:
 * ``bench/traffic/<traffic>.json`` — the traffic mix: the parameters the
   kind's generator reads, and the limits of the correctness comparison;
 * ``bench/kinds/<kind>.py``       — builds the system under test for a kind
-  of configuration (the configuration file names its ``kind``) and holds
-  its plain reference;
+  of configuration (the configuration file names its ``kind``), holds its
+  plain reference, its control and faults, and the sizes of its CPU
+  rehearsal (``SMOKE``);
 * ``bench/metrics/<metric>.py``   — one reader per metric, ``read(ctx)``;
   the parts of a split metric (``<metric>.<part>``) share it.
 
-A new cell, configuration, traffic mix or metric is a new file and a new
-entry; no file that is already there changes.
+A new cell, configuration, kind, traffic mix or metric is a new file and a
+new entry; no file that is already there changes.
 """
 from __future__ import annotations
 

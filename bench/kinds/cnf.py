@@ -3,15 +3,22 @@
 noise) solved by adaptive dopri5 through ``repro.core.solve`` with a
 gradient strategy, and the program's AdamW (``repro.optim``).
 
-The timed step is ``value_and_grad`` of the public ``cnf_nll`` (one
-controller for the whole batch) and the AdamW update.
+The traffic's ``controller`` picks the step controller.  ``lockstep`` (the
+default) is one controller for the whole batch: the timed step is
+``value_and_grad`` of the public ``cnf_nll`` and the AdamW update.
+``per_sample`` gives every sample a controller of its own, as lanes sharded
+over a data mesh of the cell's chips: the timed step composes
+``cnf_nll``'s parts (``models/per_sample.py: model_solve_ys`` over the
+augmented field) with ``mesh=`` handed to ``solve(..., batch_axis=0)``,
+since ``cnf_nll`` takes no mesh.  Each chip then solves and replays its
+own lanes, and the parameter gradient is summed across chips.
 
 ``reference_readings`` is the plain reference: the same concatsquash field,
 Hutchinson estimate and dopri5 controller written here, the accepted grid
-found by a plain while loop, and the gradient by ``jax.grad`` through a
-replay of the accepted steps with their sizes held fixed (the gradient of
-the discrete map, which the exact adjoints compute).  It imports nothing
-from the program.
+found by a plain while loop (one per sample for ``per_sample``, vmapped),
+and the gradient by ``jax.grad`` through a replay of the accepted steps with
+their sizes held fixed (the gradient of the discrete map, which the exact
+adjoints compute).  It imports nothing from the program.
 """
 from __future__ import annotations
 
@@ -21,6 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bench import compare, counts, generate
 
@@ -186,6 +194,29 @@ def _mean_nll(layers, u, eps, cfg):
     return jnp.mean(nll), nll
 
 
+def _lanes_mean_nll(layers, u, eps, cfg):
+    """Mean NLL of a batch with a controller of its own for every sample,
+    and the rows' NLL."""
+    def one(u1, e1):
+        x0 = (u1[None], jnp.zeros((1,), u1.dtype), e1[None])
+        ts, hs, n, ok = _accepted_grid(jax.lax.stop_gradient(layers), x0,
+                                       cfg)
+        return _replay_nll(layers, u1[None], e1[None], ts, hs, n, ok,
+                           cfg)[0]
+
+    nll = jax.vmap(one)(u, eps)
+    return jnp.mean(nll), nll
+
+
+def _per_sample(traffic: dict) -> bool:
+    """Does the traffic give every sample a step controller of its own?"""
+    controller = traffic.get("controller", "lockstep")
+    if controller not in ("lockstep", "per_sample"):
+        raise ValueError(f"controller {controller!r}: lockstep or "
+                         "per_sample")
+    return controller == "per_sample"
+
+
 def _adamw(opt, params, grads, m, v, t):
     b1, b2 = opt["b1"], opt["b2"]
     m = jax.tree_util.tree_map(lambda m, g: b1 * m + (1 - b1) * g, m, grads)
@@ -200,15 +231,25 @@ def _adamw(opt, params, grads, m, v, t):
     return params, m, v
 
 
-def reference_readings(cfg: dict, traffic: dict, seed: int) -> dict:
+def reference_readings(cfg: dict, traffic: dict, seed: int,
+                       devices=None) -> dict:
     """Losses, first gradient and parameter change of the first
-    ``compare_steps`` steps, in float32 at "highest"."""
+    ``compare_steps`` steps, in float32 at "highest".  With a controller
+    for every sample, the samples are spread over ``devices`` (default:
+    JAX's default device), which the samples' independence allows."""
     B = traffic["batch"]
     mix = generate.gaussian_mixture(traffic["mixture_seed"], cfg["dim"],
                                    traffic["mixture"])
+    mean_nll = _lanes_mean_nll if _per_sample(traffic) else _mean_nll
+    put, placed = jnp.asarray, {}
+    if _per_sample(traffic) and devices:
+        mesh = Mesh(np.asarray(devices), ("data",))
+        put = functools.partial(jax.device_put,
+                                device=NamedSharding(mesh, P("data")))
+        placed = {"out_shardings": NamedSharding(mesh, P())}
     with jax.default_matmul_precision("highest"):
-        make = jax.jit(lambda k: weights(k, cfg))
-        grad_fn = jax.jit(jax.grad(functools.partial(_mean_nll, cfg=cfg),
+        make = jax.jit(lambda k: weights(k, cfg), **placed)
+        grad_fn = jax.jit(jax.grad(functools.partial(mean_nll, cfg=cfg),
                                    has_aux=True))
         step_fn = jax.jit(functools.partial(_adamw, cfg["train"]))
         key = jnp.asarray(generate.seed_words(seed))
@@ -217,8 +258,7 @@ def reference_readings(cfg: dict, traffic: dict, seed: int) -> dict:
         out = {"loss": []}
         for t in range(traffic["compare_steps"]):
             b = generate.mixture_batch(seed, t, mix, B)
-            g, nll = grad_fn(params, jnp.asarray(b["u"]),
-                             jnp.asarray(b["eps"]))
+            g, nll = grad_fn(params, put(b["u"]), put(b["eps"]))
             out["loss"].append(float(jnp.mean(nll)))
             if t == 0:
                 out["grad"] = leaf_values(jax.device_get(_norms(g)))
@@ -236,10 +276,12 @@ class Job:
     def __init__(self, cell, devices, seed: int):
         from repro.core import AdaptiveConfig, SaveAt, as_gradient, solve
         from repro.models.cnf import CNFConfig, _aug_field_hutch, cnf_nll
+        from repro.models.per_sample import model_solve_ys
         from repro.optim import AdamWConfig, adamw_init, adamw_update
 
         cfg, tr = cell.config, cell.traffic
         self.cfg, self.traffic, self.seed = cfg, tr, seed
+        self.devices = devices
         self.warm_steps = tr["compare_steps"]
         self.trace_steps = tr["trace_steps"]
         self.limits = tr["limits"]
@@ -248,19 +290,52 @@ class Job:
         self.mix = generate.gaussian_mixture(tr["mixture_seed"], cfg["dim"],
                                              tr["mixture"])
         opt = cfg["train"]
-        adamw_cfg = AdamWConfig(b1=opt["b1"], b2=opt["b2"], eps=opt["eps"],
-                                weight_decay=opt["weight_decay"])
-        ccfg = CNFConfig(
+        self.adamw_cfg = adamw_cfg = AdamWConfig(
+            b1=opt["b1"], b2=opt["b2"], eps=opt["eps"],
+            weight_decay=opt["weight_decay"])
+        per_sample = _per_sample(tr)
+        self.ccfg = ccfg = CNFConfig(
             dim=cfg["dim"], hidden=tuple(cfg["hidden"]),
             n_components=cfg["n_components"], t1=cfg["t1"],
             trace=cfg["trace"], method=cfg["method"],
             grad_mode=tr["gradient"], combine_backend=cfg["combine_backend"],
             adaptive=True, rtol=cfg["rtol"], atol=cfg["atol"],
-            max_steps=cfg["max_steps"])
+            max_steps=cfg["max_steps"], per_sample=per_sample)
         precision = cfg["matmul_precision"]
+        stepping = AdaptiveConfig(rtol=cfg["rtol"], atol=cfg["atol"],
+                                  max_steps=cfg["max_steps"])
+        solve_kw = dict(saveat=SaveAt(t1=cfg["t1"]), method=cfg["method"],
+                        gradient=as_gradient(tr["gradient"]),
+                        stepping=stepping, backend=cfg["combine_backend"])
 
-        def nll(params, u, eps):
-            return cnf_nll(params, u, eps, ccfg)
+        if per_sample:
+            # lanes over a data mesh of the cell's chips; parameters and
+            # optimizer state replicated
+            self.mesh = Mesh(np.asarray(devices), ("data",))
+            self.rows = NamedSharding(self.mesh, P("data"))
+            placed = {"out_shardings": NamedSharding(self.mesh, P())}
+
+            def nll(params, u, eps):
+                """``cnf_nll`` of a per-sample config, with the mesh."""
+                def body(carry, comp):
+                    x, dlp = carry
+                    x, dlp_i, _ = model_solve_ys(
+                        _aug_field_hutch, (x, jnp.zeros_like(dlp), eps),
+                        comp, per_sample=True, mesh=self.mesh, **solve_kw)
+                    return (x, dlp + dlp_i), None
+
+                (z, dlp), _ = jax.lax.scan(
+                    body, (u, jnp.zeros(u.shape[0], u.dtype)),
+                    params["components"])
+                logpz = -0.5 * jnp.sum(z ** 2, -1) - \
+                    0.5 * cfg["dim"] * jnp.log(2 * jnp.pi)
+                return -jnp.mean(logpz - dlp)
+        else:
+            self.mesh = self.rows = None
+            placed = {}
+
+            def nll(params, u, eps):
+                return cnf_nll(params, u, eps, ccfg)
 
         def init(key):
             comps = jax.tree_util.tree_map(lambda l: l[None],
@@ -276,27 +351,26 @@ class Job:
                                                  adamw_cfg)
             return {"params": params, "opt": opt_state}, loss
 
-        def solver_counts(params, u, eps):
-            """(attempted, accepted) steps of the forward solve."""
+        def solver_stats(params, u, eps):
+            """The forward solve's stats: attempted and accepted steps,
+            per sample with a controller each (and then the shards' load)."""
             comp = jax.tree_util.tree_map(lambda l: l[0],
                                           params["components"])
+            state = (u, jnp.zeros(u.shape[0], u.dtype), eps)
+            lanes = {}
+            if per_sample:
+                state = jax.tree_util.tree_map(lambda l: l[:, None], state)
+                lanes = {"batch_axis": 0, "mesh": self.mesh}
             with jax.default_matmul_precision(precision):
-                sol = solve(_aug_field_hutch,
-                            (u, jnp.zeros(u.shape[0], u.dtype), eps), comp,
-                            saveat=SaveAt(t1=cfg["t1"]),
-                            method=cfg["method"],
-                            gradient=as_gradient(tr["gradient"]),
-                            stepping=AdaptiveConfig(
-                                rtol=cfg["rtol"], atol=cfg["atol"],
-                                max_steps=cfg["max_steps"]),
-                            backend=cfg["combine_backend"])
-            return sol.stats["n_attempts"], sol.stats["n_steps"]
+                sol = solve(_aug_field_hutch, state, comp, **solve_kw,
+                            **lanes)
+            return sol.stats
 
         self.key = jnp.asarray(generate.seed_words(seed))
-        self.state = jax.jit(init)(self.key)
+        self.state = jax.jit(init, **placed)(self.key)
         self.step_fn = jax.jit(step, donate_argnums=0)
         self.nll = nll
-        self.solver_counts = jax.jit(solver_counts)
+        self.solver_stats = jax.jit(solver_stats)
         self.readings = {"loss": []}
         self._batch = None
         self._recent = collections.deque(maxlen=self.trace_steps)
@@ -304,6 +378,8 @@ class Job:
     def _batch_of(self, i: int) -> tuple:
         b = generate.mixture_batch(self.seed, i, self.mix,
                                    self.traffic["batch"])
+        if self.rows is not None:
+            return tuple(jax.device_put((b["u"], b["eps"]), self.rows))
         return jnp.asarray(b["u"]), jnp.asarray(b["eps"])
 
     def step(self, i: int) -> float:
@@ -317,20 +393,39 @@ class Job:
         self._recent.append(i)
         return loss
 
+    def _recent_stats(self) -> list:
+        """The forward solve's stats, at the current parameters, on the
+        batches of the last steps run."""
+        return [jax.device_get(self.solver_stats(self.state["params"],
+                                                 *self._batch_of(i)))
+                for i in self._recent]
+
     def flops_per_step(self) -> float:
         """Model FLOPs of a step: every attempted step of the forward solve
         (7 evaluations of the augmented field) and the backward of every
-        accepted one (twice an evaluation), recomputation not counted.
-        The solver's counts are read, at the current parameters, on the
-        batches of the last steps run."""
+        accepted one (twice an evaluation), of every sample, recomputation
+        not counted; with a controller per sample, each sample's own
+        counts.  The solver's counts are read, at the current parameters,
+        on the batches of the last steps run."""
+        B = self.traffic["batch"]
         f_eval = counts.cnf_field_flops(self.cfg["dim"], self.cfg["hidden"],
-                                        self.traffic["batch"])
+                                        1)
         total = 0.0
-        for i in self._recent:
-            att, acc = self.solver_counts(self.state["params"],
-                                          *self._batch_of(i))
-            total += 7 * f_eval * (int(att) + 2 * int(acc))
+        for st in self._recent_stats():
+            work = np.broadcast_to(
+                np.asarray(st["n_attempts"], np.int64)
+                + 2 * np.asarray(st["n_steps"], np.int64), (B,))
+            total += 7 * f_eval * int(work.sum())
         return total / len(self._recent)
+
+    def load_imbalance(self):
+        """Max over mean of the shards' accepted steps
+        (``parallel/solve.py: with_shard_load_stats``), the mean over the
+        batches of the last steps run; None off a mesh."""
+        if self.mesh is None:
+            return None
+        return float(np.mean([float(st["load_imbalance"])
+                              for st in self._recent_stats()]))
 
     def _layers(self, tree):
         return [jax.tree_util.tree_map(lambda l: l[0], lp)
@@ -367,7 +462,8 @@ class Job:
         self._batch = None
 
     def check(self) -> dict:
-        ref = reference_readings(self.cfg, self.traffic, self.seed)
+        ref = reference_readings(self.cfg, self.traffic, self.seed,
+                                 self.devices)
         return compare.compare_training(self.readings, ref, self.limits)
 
 
@@ -385,27 +481,71 @@ CONTROL = {"matmul_precision": "high"}
 FAULTS = ("unchanged", "half_batch")
 
 
+def faults(cell) -> tuple:
+    """The faults ``plant`` can put into a job of ``cell``: with lanes
+    sharded over chips, also ``no_exchange``."""
+    return FAULTS + (("no_exchange",) if _per_sample(cell.traffic) else ())
+
+
 def plant(job: Job, fault: str) -> None:
     """Break the job's timed step: ``unchanged`` returns the state as it
     came; ``half_batch`` drops the second half of every batch, so the mean
-    is taken over the rest."""
-    from repro.optim import AdamWConfig, adamw_update
+    is taken over the rest; ``no_exchange`` leaves out the sum of the
+    gradient across chips, so that each chip steps with the gradient of its
+    own lanes (of the batch's mean NLL)."""
+    from repro.models.cnf import cnf_nll
+    from repro.optim import adamw_update
     opt = job.cfg["train"]
-    adamw_cfg = AdamWConfig(b1=opt["b1"], b2=opt["b2"], eps=opt["eps"],
-                            weight_decay=opt["weight_decay"])
     precision = job.cfg["matmul_precision"]
+    B = job.traffic["batch"]
+    if fault == "no_exchange" and job.mesh is not None:
+        def local(state, u, eps):
+            def nll(params):
+                return cnf_nll(params, u, eps, job.ccfg) * (u.shape[0] / B)
+
+            with jax.default_matmul_precision(precision):
+                loss, g = jax.value_and_grad(nll)(state["params"])
+                params, o = adamw_update(state["params"], g, state["opt"],
+                                         opt["lr"], job.adamw_cfg)
+            return {"params": params, "opt": o}, jax.lax.psum(loss, "data")
+
+        job.step_fn = jax.jit(jax.shard_map(
+            local, mesh=job.mesh, in_specs=(P(), P("data"), P("data")),
+            out_specs=(P(), P()), check_vma=False))
+        return
     if fault not in FAULTS:
         raise ValueError(fault)
-    rows = job.traffic["batch"] // (2 if fault == "half_batch" else 1)
+    rows = B // (2 if fault == "half_batch" else 1)
 
     def step(state, u, eps):
         with jax.default_matmul_precision(precision):
             loss, g = jax.value_and_grad(job.nll)(state["params"], u[:rows],
                                                   eps[:rows])
             params, o = adamw_update(state["params"], g, state["opt"],
-                                     opt["lr"], adamw_cfg)
+                                     opt["lr"], job.adamw_cfg)
         if fault == "unchanged":
             return state, loss
         return {"params": params, "opt": o}, loss
 
     job.step_fn = jax.jit(step)
+
+
+# --------------------------------------------------------------------------
+# the CPU rehearsal (tests/bench)
+# --------------------------------------------------------------------------
+
+# a 4-8-8-4 field on 8 samples, Pallas in interpret mode.  The limits are
+# taken ten times wider: on this field the parameter change of the smallest
+# leaves reads up to 1.24e-6 on the CPU, where the chip's limit, set at full
+# size, is 1e-6; every fault still reads 1e-3 or more.
+SMOKE = {
+    "config": {"dim": 4, "hidden": [8, 8], "combine_backend": "pallas",
+               "rtol": 1e-5, "atol": 1e-7},
+    "traffic": {"batch": 8, "trace_steps": 2},
+    "limits_scale": 10.0,
+    # 8 lanes on 4 host devices, 2 a device: sharding alone moves the
+    # program's readings and the reference's, each against itself on one
+    # device, by up to 2e-5 (gradient and change, 8 seeds), where one
+    # device reads 1e-6; every fault still reads 0.16 or more
+    "per_traffic": {"train-b1000-lanes-symplectic": {"limits_scale": 100.0}},
+}

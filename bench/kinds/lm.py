@@ -257,17 +257,20 @@ def _adamw(opt: dict, params, grads, m, v, t):
     return jax.tree_util.tree_map(upd, params, m, v), m, v, grads
 
 
-def reference_readings(cfg: dict, traffic: dict, seed: int) -> dict:
+def reference_readings(cfg: dict, traffic: dict, seed: int,
+                       devices=None) -> dict:
     """Losses, first gradient and parameter change of the first
     ``compare_steps`` steps, in float32 at "highest", the gradient summed
-    over blocks of rows so that it fits beside the optimizer state."""
+    over blocks of rows so that it fits beside the optimizer state.  It
+    runs on the first of ``devices`` (default: JAX's default device)."""
     rows, S = traffic["batch"], traffic["seq_len"]
     block = min(traffic["reference"]["block_rows"], rows)
     chunk = traffic["reference"]["loss_chunk"]
     opt = cfg["train"]
     n_layers = cfg["num_hidden_layers"]
-    key = jnp.asarray(generate.seed_words(seed))
-    with jax.default_matmul_precision("highest"):
+    with jax.default_matmul_precision("highest"), \
+            jax.default_device(devices[0] if devices else None):
+        key = jnp.asarray(generate.seed_words(seed))
         make = jax.jit(lambda k: hf_weights(k, cfg))
         grad_fn = jax.jit(jax.value_and_grad(
             lambda p, t, l: _nll_sum(cfg, p, t, l, chunk)))
@@ -323,6 +326,7 @@ class Job:
 
         cfg, tr = cell.config, cell.traffic
         self.cfg, self.traffic, self.seed = cfg, tr, seed
+        self.devices = devices
         self.warm_steps = tr["compare_steps"]
         self.trace_steps = tr["trace_steps"]
         self.limits = tr["limits"]
@@ -417,7 +421,8 @@ class Job:
         self._batch = None
 
     def check(self) -> dict:
-        ref = reference_readings(self.cfg, self.traffic, self.seed)
+        ref = reference_readings(self.cfg, self.traffic, self.seed,
+                                 self.devices)
         return compare.compare_training(self.readings, ref, self.limits)
 
 
@@ -433,6 +438,11 @@ def build(cell, devices, seed: int) -> Job:
 # bfloat16 parameters (AdamW keeps float32 master copies)
 CONTROL = {"param_dtype": "bfloat16"}
 FAULTS = ("unchanged", "half_batch")
+
+
+def faults(cell) -> tuple:
+    """The faults ``plant`` can put into a job of ``cell``."""
+    return FAULTS
 
 
 def plant(job: Job, fault: str) -> None:
@@ -451,3 +461,22 @@ def plant(job: Job, fault: str) -> None:
             s, {k: v[:half] for k, v in b.items()}), donate_argnums=0)
     else:
         raise ValueError(fault)
+
+
+# --------------------------------------------------------------------------
+# the CPU rehearsal (tests/bench)
+# --------------------------------------------------------------------------
+
+# a 2-layer decoder of width 32 on 2 x 16 tokens, Pallas in interpret mode;
+# the limits as the chip's
+SMOKE = {
+    "config": {"hidden_size": 32, "intermediate_size": 64,
+               "num_hidden_layers": 2, "num_attention_heads": 4,
+               "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 128,
+               "use_pallas": True,
+               "node": {"method": "euler", "n_steps": 2,
+                        "combine_backend": "pallas"}},
+    "traffic": {"batch": 2, "seq_len": 16, "trace_steps": 2,
+                "reference": {"block_rows": 1, "loss_chunk": 8}},
+    "limits_scale": 1.0,
+}

@@ -3,15 +3,15 @@
 in-process run of the harness with the look for a chip skipped.
 
 The smoke tree keeps ``BENCHMARK.json``'s cells, metrics and limits and
-shrinks only the sizes: a 2-layer LM of width 32 on 2 x 16 tokens, a CNF
-of dim 4 on 8 samples.  Pallas kernels run in interpret mode.  The CNF's
-limits are taken ten times wider: on a 4-8-8-4 field the parameter change
-of the smallest leaves reads up to 1.24e-6 on the CPU, where the chip's
-limit, set at full size, is 1e-6; every fault still reads 1e-3 or more.
+shrinks only the sizes, as each kind of configuration declares them
+(``SMOKE`` in ``bench/kinds/<kind>.py``: keys laid over the configuration
+and the traffic, and a scale of the limits).  Pallas kernels run in
+interpret mode.
 """
 from __future__ import annotations
 
 import contextlib
+import importlib
 import io
 import json
 import pathlib
@@ -21,41 +21,29 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-CONFIG_SMOKE = {
-    "lm": {"hidden_size": 32, "intermediate_size": 64,
-           "num_hidden_layers": 2, "num_attention_heads": 4,
-           "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 128,
-           "use_pallas": True,
-           "node": {"method": "euler", "n_steps": 2,
-                    "combine_backend": "pallas"}},
-    "cnf": {"dim": 4, "hidden": [8, 8], "combine_backend": "pallas",
-            "rtol": 1e-5, "atol": 1e-7},
-}
-TRAFFIC_SMOKE = {
-    "lm": {"batch": 2, "seq_len": 16, "trace_steps": 2,
-           "reference": {"block_rows": 1, "loss_chunk": 8}},
-    "cnf": {"batch": 8, "trace_steps": 2},
-}
-LIMITS_SCALE = {"lm": 1.0, "cnf": 10.0}
 
-
-def smoke_tree(tmp: pathlib.Path) -> pathlib.Path:
-    """A copy of the benchmark's cells at smoke sizes under ``tmp``."""
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+def smoke_tree(tmp: pathlib.Path, src: pathlib.Path = ROOT) -> pathlib.Path:
+    """A copy under ``tmp`` of the benchmark at ``src`` with every cell at
+    the smoke sizes of its kind (``bench/kinds/<kind>.py: SMOKE``), where
+    ``SMOKE["per_traffic"][<traffic>]`` overrides for one traffic mix."""
+    spec = json.loads((src / "BENCHMARK.json").read_text())
     (tmp / "bench" / "configs").mkdir(parents=True, exist_ok=True)
     (tmp / "bench" / "traffic").mkdir(parents=True, exist_ok=True)
-    kinds = {}
+    smoke = {}
     for c in spec["configs"]:
-        cfg = json.loads((ROOT / c["file"]).read_text())
-        kinds[c["name"]] = cfg["kind"]
-        cfg.update(CONFIG_SMOKE[cfg["kind"]])
+        cfg = json.loads((src / c["file"]).read_text())
+        smoke[c["name"]] = importlib.import_module(
+            f"bench.kinds.{cfg['kind']}").SMOKE
+        cfg.update(smoke[c["name"]]["config"])
         (tmp / c["file"]).write_text(json.dumps(cfg))
     for w in spec["workloads"]:
-        tr = json.loads((ROOT / "bench" / "traffic" /
+        tr = json.loads((src / "bench" / "traffic" /
                          (w["traffic"] + ".json")).read_text())
-        kind = kinds[w["config"]]
-        tr.update(TRAFFIC_SMOKE[kind])
-        tr["limits"] = {k: v * LIMITS_SCALE[kind]
+        sizes = smoke[w["config"]]
+        sizes = {**sizes, **sizes.get("per_traffic", {}).get(w["traffic"],
+                                                            {})}
+        tr.update(sizes["traffic"])
+        tr["limits"] = {k: v * sizes["limits_scale"]
                         for k, v in tr["limits"].items()}
         (tmp / "bench" / "traffic" / (w["traffic"] + ".json")).write_text(
             json.dumps(tr))

@@ -2,8 +2,8 @@
 
 Each fault a cell can have is planted in the program's timed step
 (``kind.plant``) under an otherwise whole run at smoke size, against the
-limits the cell carries (the CNF's ten times wider at this size, as
-``bench_testing`` says).  The control, the program with its precision one
+limits the cell carries (the CNF's ten times wider at this size, as its
+kind's ``SMOKE`` says).  The control, the program with its precision one
 step below the configuration's (``kind.CONTROL``), fails too where the CPU
 can lower it: bfloat16 parameters for the LM.  (XLA:CPU computes float32
 matmuls exactly at every precision setting, so the CNF's control, matmuls
