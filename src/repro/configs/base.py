@@ -13,6 +13,7 @@ from typing import Optional, Tuple
 from repro.nn.attention import AttnConfig
 from repro.nn.mamba import MambaConfig
 from repro.nn.moe import MoEConfig
+from repro.nn.rope import YaRN
 from repro.nn.xlstm import XLSTMConfig
 
 
@@ -68,6 +69,7 @@ class ArchConfig:
     window: Optional[int] = None
     rope_theta: float = 10000.0
     rotary_pct: float = 1.0
+    rope_scaling: Optional[YaRN] = None   # read by MLA
     mla_kv_lora: int = 0           # >0 enables MLA fields
     mla_rope_dim: int = 64
     mla_nope_dim: int = 128
@@ -78,6 +80,12 @@ class ArchConfig:
     moe_d_ff: int = 0
     moe_shared: int = 0
     moe_shared_d_ff: int = 0
+    moe_norm_topk_prob: bool = True
+    moe_seq_aux: bool = False
+    moe_aux_alpha: float = 0.01
+    # (first, n): the routed experts this chip holds of each expert layer;
+    # None computes all of them under the capacity-factor dispatch
+    moe_held: Optional[Tuple[int, int]] = None
     # ssm
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
@@ -113,13 +121,16 @@ class ArchConfig:
             rope_theta=self.rope_theta, rotary_pct=self.rotary_pct,
             mla=self.mla_kv_lora > 0, kv_lora=self.mla_kv_lora or 512,
             rope_head_dim=self.mla_rope_dim, nope_head_dim=self.mla_nope_dim,
-            v_head_dim=self.mla_v_dim)
+            v_head_dim=self.mla_v_dim, rope_scaling=self.rope_scaling)
 
     def moe_config(self) -> MoEConfig:
         return MoEConfig(
             d_model=self.d_model, d_ff=self.moe_d_ff or self.d_ff,
             n_experts=self.moe_experts, top_k=self.moe_top_k,
-            n_shared=self.moe_shared, shared_d_ff=self.moe_shared_d_ff)
+            n_shared=self.moe_shared, shared_d_ff=self.moe_shared_d_ff,
+            router_aux_weight=self.moe_aux_alpha,
+            norm_topk_prob=self.moe_norm_topk_prob,
+            seq_aux=self.moe_seq_aux, held=self.moe_held)
 
     def mamba_config(self) -> MambaConfig:
         return MambaConfig(d_model=self.d_model,

@@ -4,11 +4,14 @@ Online-softmax attention tiled for the TPU memory hierarchy:
 
   grid = (B, H, n_q_blocks, n_kv_blocks)   # kv innermost => sequential
   q tile   (block_q, D)  in VMEM, revisited across the kv dimension
-  k/v tile (block_k, D)  in VMEM, GQA-mapped: kv head = h // (H // Hkv)
-  scratch  m (block_q,1), l (block_q,1), acc (block_q, D) — float32 VMEM
+  k tile   (block_k, D), v tile (block_k, Dv) in VMEM, GQA-mapped:
+           kv head = h // (H // Hkv)
+  scratch  m (block_q,1), l (block_q,1), acc (block_q, Dv) — float32 VMEM
 
-The MXU consumes the (block_q, D) x (D, block_k) logit matmul and the
-(block_q, block_k) x (block_k, D) value matmul; block sizes default to
+The value head dim Dv may differ from the query/key one D (DeepSeek's latent
+attention: qk 192, v 128).  The MXU consumes the (block_q, D) x (D, block_k)
+logit matmul and the (block_q, block_k) x (block_k, Dv) value matmul; block
+sizes default to
 128 so every matmul dimension is MXU-aligned.  Fully-masked tiles (beyond
 the causal frontier or behind the sliding window) are skipped via pl.when,
 giving the ~2x causal FLOP saving and the O(S*w) SWA cost that makes
@@ -99,9 +102,10 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            block_q: int = DEFAULT_BLOCK_Q,
                            block_k: int = DEFAULT_BLOCK_K,
                            interpret: bool = False) -> jnp.ndarray:
-    """q: (B, H, Sq, D); k, v: (B, Hkv, Sk, D). Returns (B, H, Sq, D)."""
+    """q, k: (B, H|Hkv, S, D); v: (B, Hkv, Sk, Dv). Returns (B, H, Sq, Dv)."""
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
+    Dv = v.shape[-1]
     assert H % Hkv == 0, (H, Hkv)
     group = H // Hkv
     scale = scale if scale is not None else D ** -0.5
@@ -129,16 +133,16 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, bk, D),
                          lambda b, h, iq, ik: (b, h // group, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D),
+            pl.BlockSpec((1, 1, bk, Dv),
                          lambda b, h, iq, ik: (b, h // group, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, D),
+        out_specs=pl.BlockSpec((1, 1, bq, Dv),
                                lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, sq_pad, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, sq_pad, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(qp, kp, vp)

@@ -1,8 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These are the ground truth for the interpret-mode kernel tests and the
-default implementations used by the distributed dry-run (the CPU container
-cannot lower Mosaic TPU kernels; see DESIGN.md §2).
+implementations off the TPU, where Mosaic kernels do not lower; the
+attention oracles are also the backward of the kernels' custom VJPs
+(``ops.py``).
 """
 from __future__ import annotations
 
@@ -84,7 +85,7 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                   scale: Optional[float] = None) -> jnp.ndarray:
     """Multi-head attention with GQA, causal masking and sliding window.
 
-    q: (B, H, Sq, D); k, v: (B, Hkv, Sk, D); H % Hkv == 0.
+    q: (B, H, Sq, D); k: (B, Hkv, Sk, D); v: (B, Hkv, Sk, Dv); H % Hkv == 0.
     ``q_offset`` is the absolute position of q[..., 0, :] (decode: Sk - Sq).
     window w: query j attends keys i with j - w < i <= j (SWA, mixtral-style).
     """
@@ -186,6 +187,6 @@ def attention_blocked_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return None, one_block(args)
 
     _, ob = jax.lax.scan(body, None, (qb, jnp.arange(nblocks)))
-    out = ob.transpose(1, 2, 0, 3, 4).reshape(B, H, Sq, D)
+    out = ob.transpose(1, 2, 0, 3, 4).reshape(B, H, Sq, v.shape[-1])
     return out.astype(q.dtype)
 

@@ -11,7 +11,7 @@ from repro.nn.attention import (gqa_attention, init_gqa, init_gqa_cache,
 from repro.nn.common import no_shard, split_keys
 from repro.nn.mamba import init_mamba, init_mamba_state, mamba_forward
 from repro.nn.mlp import init_swiglu, swiglu
-from repro.nn.moe import init_moe, moe_ffn
+from repro.nn.moe import Routing, init_moe, moe_ffn, moe_route
 from repro.nn.norm import init_rmsnorm, rmsnorm
 from repro.nn.xlstm import (init_mlstm, init_mlstm_state, init_slstm,
                             init_slstm_state, mlstm_forward, slstm_forward)
@@ -58,16 +58,11 @@ def init_layer_cache(spec: LayerSpec, cfg: ArchConfig, batch: int,
     raise ValueError(spec.mixer)
 
 
-def layer_forward(p, x, spec: LayerSpec, cfg: ArchConfig, *,
-                  cache: Optional[Any] = None, pos=None, positions=None,
-                  shard=no_shard, causal: bool = True):
-    """Pre-norm residual block: x + mixer(norm(x)) [+ ffn(norm(x))].
-
-    Returns (x, new_cache, aux_loss)."""
-    eps = cfg.norm_eps
+def _mixer_residual(p, x, spec: LayerSpec, cfg: ArchConfig, *, cache, pos,
+                    positions, shard, causal):
+    """x + mixer(norm(x)): (x, new_cache)."""
     up = cfg.use_pallas
-    rs = cfg.residual_scale
-    h = rmsnorm(p["mixer_norm"], x, eps=eps, use_pallas=up)
+    h = rmsnorm(p["mixer_norm"], x, eps=cfg.norm_eps, use_pallas=up)
     if spec.mixer == "attn":
         y, new_cache = gqa_attention(p["attn"], h, cfg.attn_config(),
                                      positions=positions, cache=cache,
@@ -88,13 +83,34 @@ def layer_forward(p, x, spec: LayerSpec, cfg: ArchConfig, *,
                                      state=cache, shard=shard)
     else:
         raise ValueError(spec.mixer)
-    x = x + rs * y
+    return x + cfg.residual_scale * y, new_cache
+
+
+def layer_forward(p, x, spec: LayerSpec, cfg: ArchConfig, *,
+                  cache: Optional[Any] = None, pos=None, positions=None,
+                  shard=no_shard, causal: bool = True):
+    """Pre-norm residual block: x + mixer(norm(x)) [+ ffn(norm(x))].
+
+    Returns (x, new_cache, aux_loss)."""
+    x, new_cache = _mixer_residual(p, x, spec, cfg, cache=cache, pos=pos,
+                                   positions=positions, shard=shard,
+                                   causal=causal)
     aux = jnp.zeros((), jnp.float32)
     if spec.ffn != "none":
-        h = rmsnorm(p["ffn_norm"], x, eps=eps, use_pallas=up)
+        h = rmsnorm(p["ffn_norm"], x, eps=cfg.norm_eps,
+                    use_pallas=cfg.use_pallas)
         if spec.ffn == "dense":
             y = swiglu(p["mlp"], h, shard=shard)
         else:
             y, aux = moe_ffn(p["moe"], h, cfg.moe_config(), shard=shard)
-        x = x + rs * y
+        x = x + cfg.residual_scale * y
     return x, new_cache, aux
+
+
+def layer_routing(p, x, spec: LayerSpec, cfg: ArchConfig) -> Routing:
+    """The routing of an expert layer for its input x (training shapes):
+    what ``moe_route`` decides inside ``layer_forward``."""
+    x, _ = _mixer_residual(p, x, spec, cfg, cache=None, pos=None,
+                           positions=None, shard=no_shard, causal=True)
+    h = rmsnorm(p["ffn_norm"], x, eps=cfg.norm_eps, use_pallas=cfg.use_pallas)
+    return moe_route(p["moe"], h, cfg.moe_config())

@@ -1,15 +1,17 @@
 """Decoder-only LM over an arbitrary layer pattern (dense/MoE/SSM/hybrid).
 
-Depth structure: optional prefix layers (e.g. deepseek's dense first layer)
-followed by ``n_repeats`` copies of the repeating ``pattern`` unit, executed
-with lax.scan over stacked unit params (fast 512-device compiles).
+Depth structure: optional prefix layers (e.g. deepseek's dense first layer,
+run discretely in every mode, under the ``prefix_layers`` scope) followed
+by ``n_repeats`` copies of the repeating ``pattern`` unit, executed with
+lax.scan over stacked unit params (fast 512-device compiles).
 
 Training modes:
   * discrete (default): standard residual stack; optional jax.checkpoint
     around each scanned unit (cfg.remat).
   * node_mode (cfg.node.mode == "node"): the paper — depth becomes ODE time,
     f(x, t) = R * (unit_{floor(tR)}(x) - x), integrated by the configured RK
-    method with the configured gradient scheme (symplectic adjoint, etc.).
+    method with the configured gradient scheme (symplectic adjoint, etc.);
+    the units' balance loss rides along as a scalar of the state.
     With method="euler", n_steps=R this reproduces the discrete stack
     EXACTLY (tests assert it), so the paper's memory result applies to the
     unmodified architecture.
@@ -153,6 +155,41 @@ def _head(params, cfg, x, shard):
     return shard(logits, ("batch", "seq", "vocab"))
 
 
+def _prefix_forward(params, cfg: ArchConfig, x, *, caches, pos, positions,
+                    shard, mode):
+    """The layers before the scanned units, run discretely under the
+    ``prefix_layers`` scope (each checkpointed in training under remat):
+    (x, new prefix caches, aux)."""
+    aux_total = jnp.zeros((), jnp.float32)
+    new_prefix = []
+    with jax.named_scope(scopes.PREFIX_LAYERS):
+        for i, spec in enumerate(cfg.prefix):
+            c = None if caches is None else caches["prefix"][i]
+
+            def run(lp, xx, cc, spec=spec):
+                return layer_forward(lp, xx, spec, cfg, cache=cc, pos=pos,
+                                     positions=positions, shard=shard)
+
+            if cfg.remat and mode == "train":
+                run = jax.checkpoint(run)
+            x, nc, a = run(params[f"prefix_{i}"], x, c)
+            new_prefix.append(nc)
+            aux_total = aux_total + a
+    return x, new_prefix, aux_total
+
+
+def depth_input(params, cfg: ArchConfig, tokens, *, extra_embeds=None,
+                shard=no_shard):
+    """The input of the scanned units in training: the embedded tokens
+    after the prefix layers, as ``lm_forward`` computes it (the ``x`` that
+    ``node_depth_states`` takes)."""
+    x = _embed(params, cfg, tokens, extra_embeds, shard)
+    x, _, _ = _prefix_forward(params, cfg, x, caches=None, pos=None,
+                              positions=jnp.arange(x.shape[1]), shard=shard,
+                              mode="train")
+    return x
+
+
 def lm_forward(params, cfg: ArchConfig, tokens, *, caches=None, pos=None,
                extra_embeds=None, shard=no_shard, mode: str = "train",
                return_hidden: bool = False):
@@ -175,19 +212,16 @@ def lm_forward(params, cfg: ArchConfig, tokens, *, caches=None, pos=None,
     S_total = x.shape[1]
     positions = jnp.arange(S_total) if pos is None else None
 
-    if cfg.node.mode == "node" and mode == "train":
-        logits_x = _node_depth_solve(params, cfg, x, shard)
-        return finish(logits_x, None, jnp.zeros((), jnp.float32))
+    x, new_prefix, aux_total = _prefix_forward(
+        params, cfg, x, caches=caches, pos=pos, positions=positions,
+        shard=shard, mode=mode)
 
-    aux_total = jnp.zeros((), jnp.float32)
-    new_prefix = []
-    for i, spec in enumerate(cfg.prefix):
-        c = None if caches is None else caches["prefix"][i]
-        x, nc, a = layer_forward(params[f"prefix_{i}"], x, spec, cfg,
-                                 cache=c, pos=pos, positions=positions,
-                                 shard=shard)
-        new_prefix.append(nc)
-        aux_total = aux_total + a
+    if cfg.node.mode == "node" and mode == "train":
+        if not _carries_aux(cfg):
+            return finish(_node_depth_solve(params, cfg, x, shard), None,
+                          aux_total)
+        x, aux = _node_depth_solve(params, cfg, x, shard)
+        return finish(x, None, aux_total + aux)
 
     unit_caches = None if caches is None else caches["unit"]
 
@@ -246,29 +280,48 @@ def lm_forward(params, cfg: ArchConfig, tokens, *, caches=None, pos=None,
 # node mode: depth-time ODE over the repeat units (the paper's technique)
 # ---------------------------------------------------------------------------
 
+def _carries_aux(cfg: ArchConfig) -> bool:
+    """Does a unit produce a balance loss (an expert layer)?"""
+    return any(spec.ffn == "moe" for spec in cfg.pattern)
+
+
 def _depth_field(cfg: ArchConfig, shard):
     """f(x, t) = R * (unit_{floor(tR)}(x) - x): depth-time vector field
     shared by the training solve and the depth-observation probe.  It
     declares the one unit it reads at time t (a ``SlicedField``), so the
-    symplectic backward differentiates and accumulates that unit alone."""
+    symplectic backward differentiates and accumulates that unit alone.
+
+    Where the units produce a balance loss (``_carries_aux``) the state is
+    ``(x, aux)`` and aux's field is ``R * aux_unit(x)``: euler with
+    ``n_steps = R`` sums the units' losses exactly, as the discrete stack
+    does."""
     R = cfg.n_repeats
+    carry_aux = _carries_aux(cfg)
 
     def index(t):
         return jnp.clip(jnp.floor(t * R).astype(jnp.int32), 0, R - 1)
 
-    def apply(xs, t, up):
-        y, _, _ = _unit_forward(up, xs, cfg, shard=shard)
+    def apply(state, t, up):
+        xs = state[0] if carry_aux else state
+        y, _, aux = _unit_forward(up, xs, cfg, shard=shard)
         # the symplectic adjoint SAVES the step states {x_n}; keep them
         # sequence-sharded like the discrete-mode carries
-        return shard((y - xs) * float(R), ("batch", "seq_carry", "embed"))
+        dx = shard((y - xs) * float(R), ("batch", "seq_carry", "embed"))
+        return (dx, aux * float(R)) if carry_aux else dx
 
     return SlicedField(index, apply)
 
 
+def _initial_state(cfg: ArchConfig, x):
+    return (x, jnp.zeros((), jnp.float32)) if _carries_aux(cfg) else x
+
+
 def _node_depth_solve(params, cfg: ArchConfig, x, shard):
+    """The final depth state: x, or (x, summed aux) where units carry one."""
     n_steps = cfg.node.n_steps or cfg.n_repeats
-    return solve(_depth_field(cfg, shard), x, params["unit"],
-                 saveat=SaveAt(t1=1.0), method=cfg.node.method,
+    return solve(_depth_field(cfg, shard), _initial_state(cfg, x),
+                 params["unit"], saveat=SaveAt(t1=1.0),
+                 method=cfg.node.method,
                  gradient=as_gradient(cfg.node.grad_mode),
                  stepping=n_steps,
                  backend=cfg.node.combine_backend).ys
@@ -277,6 +330,7 @@ def _node_depth_solve(params, cfg: ArchConfig, x, shard):
 def node_depth_states(params, cfg: ArchConfig, x, depths, shard=no_shard):
     """Observe the depth-time ODE at interior depths (probing/logit-lens).
 
+    ``x`` is the input of the scanned units (``depth_input``).
     ``depths``: monotone observation times in (0, 1] of the depth ODE
     (depth d in [0, n_repeats] corresponds to t = d / n_repeats).  Returns
     hidden states stacked (len(depths), B, S, E) from ONE multi-observation
@@ -292,8 +346,10 @@ def node_depth_states(params, cfg: ArchConfig, x, depths, shard=no_shard):
     # per-segment step budget: keep the TOTAL grid comparable to the
     # unobserved solve's n_steps over [0, 1]
     seg_steps = max(1, -(-n_steps // depths.shape[0]))
-    return solve(_depth_field(cfg, shard), x, params["unit"],
-                 saveat=SaveAt(ts=depths), method=cfg.node.method,
-                 gradient=as_gradient(cfg.node.grad_mode),
-                 stepping=seg_steps,
-                 backend=cfg.node.combine_backend).ys
+    ys = solve(_depth_field(cfg, shard), _initial_state(cfg, x),
+               params["unit"], saveat=SaveAt(ts=depths),
+               method=cfg.node.method,
+               gradient=as_gradient(cfg.node.grad_mode),
+               stepping=seg_steps,
+               backend=cfg.node.combine_backend).ys
+    return ys[0] if _carries_aux(cfg) else ys

@@ -18,7 +18,8 @@ from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from .common import dense_init, no_shard, split_keys
 from .norm import init_rmsnorm, rmsnorm
-from .rope import apply_rope, rope_freqs
+from .rope import (YaRN, apply_rope, rope_freqs, yarn_amplitude,
+                   yarn_softmax_scale)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,6 +39,7 @@ class AttnConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+    rope_scaling: Optional[YaRN] = None
 
 
 # ---------------------------------------------------------------------------
@@ -150,13 +152,17 @@ def init_mla(key, cfg: AttnConfig, dtype=jnp.float32):
 
 def mla_attention(p, x, cfg: AttnConfig, *, positions=None, cache=None,
                   pos=None, shard=no_shard, use_pallas=None):
-    """DeepSeek-V2 MLA. Prefill materializes per-head K/V (flash-compatible);
-    decode runs the absorbed formulation against the compressed cache."""
+    """DeepSeek-V2 MLA.  Training and prefill expand the latent into
+    per-head K (qk head dim nope + rope) and V (v head dim) and run the
+    flash kernel; decode runs the absorbed formulation against the
+    compressed cache.  RoPE on the rope dims, YaRN-scaled where the config
+    says."""
     B, S, d = x.shape
     H = cfg.n_heads
     dn, dr, dv = cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
-    inv = rope_freqs(dr, cfg.rope_theta, dr)
-    scale = (dn + dr) ** -0.5
+    inv = rope_freqs(dr, cfg.rope_theta, dr, yarn=cfg.rope_scaling)
+    amp = yarn_amplitude(cfg.rope_scaling)
+    scale = yarn_softmax_scale(dn + dr, cfg.rope_scaling)
 
     q = (x @ p["wq"]).reshape(B, S, H, dn + dr).transpose(0, 2, 1, 3)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
@@ -165,19 +171,16 @@ def mla_attention(p, x, cfg: AttnConfig, *, positions=None, cache=None,
 
     if pos is None:
         pp = positions if positions is not None else jnp.arange(S)
-        q_rope = apply_rope(q_rope, pp, inv)
-        kr = apply_rope(kr, pp, inv)
+        q_rope = apply_rope(q_rope, pp, inv, amplitude=amp)
+        kr = apply_rope(kr, pp, inv, amplitude=amp)
         k_nope = (ckv @ p["wuk"]).reshape(B, S, H, dn).transpose(0, 2, 1, 3)
         v = (ckv @ p["wuv"]).reshape(B, S, H, dv).transpose(0, 2, 1, 3)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(kr, (B, H, S, dr))], -1)
         qq = jnp.concatenate([q_nope, q_rope], -1)
         qq = shard(qq, ("batch", "heads", "seq", "head_dim"))
-        # v is dv-dim; pad to qk dim not needed: ops.attention requires same
-        # D for q/k only; v can differ -> use ref einsum path via kops with
-        # v dim dv (flash kernel assumes same D; use ref for MLA).
-        out = kref.attention_ref(qq, k, v, causal=True, q_offset=0,
-                                 scale=scale)
+        out = kops.attention(qq, k, v, causal=True, q_offset=0, scale=scale,
+                             use_pallas=use_pallas)
         new_cache = None
         if cache is not None:
             new_cache = {
@@ -191,8 +194,8 @@ def mla_attention(p, x, cfg: AttnConfig, *, positions=None, cache=None,
             }
     else:
         ppos = jnp.reshape(pos, (1,))
-        q_rope = apply_rope(q_rope, ppos, inv)
-        kr = apply_rope(kr, ppos, inv)
+        q_rope = apply_rope(q_rope, ppos, inv, amplitude=amp)
+        kr = apply_rope(kr, ppos, inv, amplitude=amp)
         z = jnp.zeros((), dtype=jnp.asarray(pos).dtype)
         new_cache = {
             "ckv": jax.lax.dynamic_update_slice(

@@ -13,6 +13,11 @@ backward of a scope carries ``transpose(jvp(<name>))`` in its name stack.
 * ``ATTENTION``          — ``kernels.ops.attention``, kernel or jnp.
 * ``LM_LOSS``            — ``train.losses``: head and cross entropy.
 * ``OPTIMIZER``          — ``optim``: global-norm clip and AdamW.
+* ``PREFIX_LAYERS``      — ``models.lm``: the layers before the scanned
+  units (DeepSeek's dense first layer), run discretely.
+* ``MOE_ROUTE``          — ``nn.moe``: the held-share layer's router, top-k,
+  balance loss, sort, gather and scatter.
+* ``MOE_EXPERTS``        — ``nn.moe``: its grouped expert matmuls.
 """
 
 ODE_SOLVE = "ode_solve"
@@ -20,3 +25,6 @@ ADJOINT_ACCUMULATE = "adjoint_accumulate"
 ATTENTION = "attention"
 LM_LOSS = "lm_loss"
 OPTIMIZER = "optimizer"
+PREFIX_LAYERS = "prefix_layers"
+MOE_ROUTE = "moe_route"
+MOE_EXPERTS = "moe_experts"

@@ -87,7 +87,8 @@ def test_prefill_decode_smoke(arch_id):
 @pytest.mark.parametrize("arch_id", [
     "qwen3-0.6b",
     pytest.param("mixtral-8x7b", marks=pytest.mark.slow),
-    pytest.param("xlstm-1.3b", marks=pytest.mark.slow)])
+    pytest.param("xlstm-1.3b", marks=pytest.mark.slow),
+    pytest.param("deepseek-v2-lite-16b", marks=pytest.mark.slow)])
 def test_node_mode_smoke(arch_id):
     """The paper's technique on a reduced config of each family kind."""
     arch = get_smoke_arch(arch_id).with_(

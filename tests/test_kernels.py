@@ -154,6 +154,38 @@ def test_flash_attention_swa_equals_full_when_window_covers():
                                atol=1e-6)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("Sq,Sk,q_offset", [(128, 128, 0), (100, 100, 0),
+                                            (1, 200, 199)])
+def test_flash_attention_v_head_dim_differs(Sq, Sk, q_offset, dtype):
+    """Latent attention's head dims: qk 192, v 128 (DeepSeek-V2)."""
+    keys = jax.random.split(jax.random.PRNGKey(13), 3)
+    q = _rand(keys[0], (1, 2, Sq, 192), dtype)
+    k = _rand(keys[1], (1, 2, Sk, 192), dtype)
+    v = _rand(keys[2], (1, 2, Sk, 128), dtype)
+    got = flash_attention_pallas(q, k, v, causal=True, q_offset=q_offset,
+                                 scale=0.11472, block_q=64, block_k=64,
+                                 interpret=True)
+    want = ref.attention_ref(q, k, v, causal=True, q_offset=q_offset,
+                             scale=0.11472)
+    assert got.shape == want.shape == (1, 2, Sq, 128)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **TOL[dtype])
+
+
+@pytest.mark.parametrize("Dv", [16, 48])
+def test_attention_blocked_ref_v_head_dim_differs(Dv):
+    keys = jax.random.split(jax.random.PRNGKey(14), 3)
+    q = _rand(keys[0], (2, 4, 64, 24), jnp.float32)
+    k = _rand(keys[1], (2, 2, 64, 24), jnp.float32)
+    v = _rand(keys[2], (2, 2, 64, Dv), jnp.float32)
+    got = ref.attention_blocked_ref(q, k, v, causal=True, block_q=16)
+    want = ref.attention_ref(q, k, v, causal=True)
+    assert got.shape == want.shape == (2, 4, 64, Dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
 # --- reverse mode through the Pallas branches of ops ------------------------
 #
 # ops.rms_norm / ops.attention wrap the kernels in custom VJPs whose backward
