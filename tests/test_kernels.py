@@ -17,7 +17,9 @@ except ImportError:  # minimal container: run fixed examples instead
 
 from repro.kernels import ref
 from repro.kernels.butcher_combine import butcher_combine_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention import (_kv_block, _tile_class,
+                                           flash_attention_pallas,
+                                           flash_plan)
 from repro.kernels.rmsnorm import rms_norm_pallas
 
 TOL = {jnp.float32: dict(rtol=1e-5, atol=1e-5),
@@ -171,6 +173,191 @@ def test_flash_attention_v_head_dim_differs(Sq, Sk, q_offset, dtype):
     assert got.shape == want.shape == (1, 2, Sq, 128)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", ATTN_CASES)
+def test_flash_attention_planned_blocks_match_ref(case, dtype):
+    """The blocks ``flash_plan`` chooses when the caller names none."""
+    B, H, Hkv, Sq, Sk, D, causal, window, q_offset = case
+    keys = jax.random.split(jax.random.PRNGKey(43), 3)
+    q = _rand(keys[0], (B, H, Sq, D), dtype)
+    k = _rand(keys[1], (B, Hkv, Sk, D), dtype)
+    v = _rand(keys[2], (B, Hkv, Sk, D), dtype)
+    got = flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                 q_offset=q_offset, interpret=True)
+    want = ref.attention_ref(q, k, v, causal=causal, window=window,
+                             q_offset=q_offset)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **TOL[dtype])
+
+
+PLANNED_CASES = {
+    # (B, H, Hkv, Sq, Sk, D, Dv, causal, window, q_offset, scale, blocks)
+    # qwen3-0.6b's heads (group 2, D 128) at a short length: 512 blocks,
+    # one interior tile, two on the diagonal, one dead
+    "qwen3_heads": (1, 4, 2, 1024, 1024, 128, 128, True, None, 0, None,
+                    None),
+    # DeepSeek-V2's latent heads: qk 192, v 128
+    "latent_heads": (1, 2, 2, 1024, 1024, 192, 128, True, None, 0, 0.11472,
+                     None),
+    # a length no block divides: the last kv tile holds pad keys
+    "ragged": (1, 4, 2, 1000, 1000, 64, 64, True, None, 0, None, None),
+    # the window's far edge inside 512 tiles, an interior tile behind the
+    # diagonal
+    "window_edge": (1, 4, 1, 2048, 2048, 64, 64, True, 1100, 0, None, None),
+    # small tiles: interior tiles inside the window band, dead ones behind it
+    "window_band": (1, 4, 2, 512, 512, 64, 64, True, 200, 0, None,
+                    (64, 64)),
+    # chunked prefill: an interior kv tile before the diagonal
+    "q_offset": (1, 4, 2, 256, 1024, 64, 64, True, None, 768, None, None),
+    "q_offset_ragged": (1, 4, 2, 200, 1000, 64, 64, True, None, 800, None,
+                        None),
+    "non_causal_ragged": (1, 2, 1, 300, 700, 64, 32, False, None, 0, None,
+                          None),
+}
+
+
+@pytest.mark.parametrize("case", list(PLANNED_CASES))
+def test_flash_attention_edge_and_interior_tiles_match_ref(case):
+    (B, H, Hkv, Sq, Sk, D, Dv, causal, window, q_offset, scale,
+     blocks) = PLANNED_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(17), 3)
+    q = _rand(keys[0], (B, H, Sq, D), jnp.float32)
+    k = _rand(keys[1], (B, Hkv, Sk, D), jnp.float32)
+    v = _rand(keys[2], (B, Hkv, Sk, Dv), jnp.float32)
+    kw = dict(causal=causal, window=window, q_offset=q_offset, scale=scale)
+    bq, bk = blocks or (None, None)
+    plan = flash_plan(B, H, Hkv, Sq, Sk, D, Dv, causal=causal, window=window,
+                      q_offset=q_offset, block_q=bq, block_k=bk)
+    assert 0 < plan.edge_tiles < plan.live_tiles, plan   # both kinds run
+    got = flash_attention_pallas(q, k, v, block_q=bq, block_k=bk,
+                                 interpret=True, **kw)
+    want = ref.attention_ref(q, k, v, **kw)
+    assert got.shape == want.shape == (B, H, Sq, Dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+# --- the tile schedule -------------------------------------------------------
+
+PLAN_CASES = {
+    # (B, H, Hkv, Sq, Sk, D, Dv, causal, window, q_offset, blocks)
+    "causal": (2, 4, 2, 1024, 1024, 128, 128, True, None, 0, None),
+    "causal_small_blocks": (1, 2, 1, 512, 512, 64, 64, True, None, 0,
+                            (128, 64)),
+    "ragged": (1, 4, 4, 1000, 1000, 64, 64, True, None, 0, None),
+    "window": (1, 8, 2, 2048, 2048, 128, 128, True, 700, 0, None),
+    "window_small_blocks": (1, 2, 2, 640, 640, 64, 64, True, 150, 0,
+                            (128, 128)),
+    "decode": (4, 16, 8, 1, 4096, 128, 128, True, None, 4095, None),
+    "decode_window": (1, 16, 8, 1, 300, 64, 64, True, 128, 299, None),
+    "chunked_prefill": (2, 16, 8, 256, 2048, 128, 128, True, None, 1792,
+                        None),
+    "non_causal": (4, 8, 8, 600, 700, 64, 64, False, None, 0, None),
+    "latent": (4, 16, 16, 4096, 4096, 192, 128, True, None, 0, None),
+}
+
+
+def _brute_tiles(Sq, Sk, causal, window, q_offset, bq, bk):
+    """(live, inner) per tile from the mask itself: a tile is live if the
+    mask keeps a pair of its real queries, inner if it keeps every pair of
+    them (pad keys count as masked)."""
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    qpos = q_offset + np.arange(Sq)[:, None]
+    kpos = np.arange(nk * bk)[None, :]
+    keep = kpos < Sk
+    if causal:
+        keep = keep & (kpos <= qpos)
+    if window is not None:
+        keep = keep & (kpos > qpos - window)
+    keep = np.broadcast_to(keep, (Sq, nk * bk))
+    live = np.zeros((nq, nk), bool)
+    inner = np.zeros((nq, nk), bool)
+    for iq in range(nq):
+        for ik in range(nk):
+            t = keep[iq * bq:(iq + 1) * bq, ik * bk:(ik + 1) * bk]
+            live[iq, ik], inner[iq, ik] = t.any(), t.all()
+    return live, inner
+
+
+@pytest.mark.parametrize("case", list(PLAN_CASES))
+def test_flash_plan_counts_match_the_mask(case):
+    B, H, Hkv, Sq, Sk, D, Dv, causal, window, q_offset, blocks = \
+        PLAN_CASES[case]
+    bq, bk = blocks or (None, None)
+    plan = flash_plan(B, H, Hkv, Sq, Sk, D, Dv, causal=causal, window=window,
+                      q_offset=q_offset, block_q=bq, block_k=bk)
+    bq, bk = plan.block_q, plan.block_k
+    live, inner = _brute_tiles(Sq, Sk, causal, window, q_offset, bq, bk)
+    nq, nk = live.shape
+    assert plan.grid == (B, Hkv, nq, nk)
+    assert plan.grid_steps == B * Hkv * nq * nk
+    assert plan.live_tiles == B * Hkv * int(live.sum())
+    assert plan.edge_tiles == B * Hkv * int((live & ~inner).sum())
+    # the kernel's own tile test agrees tile by tile
+    q_lo = np.arange(nq)[:, None] * bq + q_offset
+    k_lo = np.arange(nk)[None, :] * bk
+    k_live, k_inner = _tile_class(q_lo, k_lo, bq=bq, bk=bk,
+                                  q_end=q_offset + Sq - 1, kv_len=Sk,
+                                  causal=causal, window=window)
+    np.testing.assert_array_equal(np.broadcast_to(k_live, live.shape), live)
+    np.testing.assert_array_equal(np.broadcast_to(k_inner, live.shape),
+                                  inner)
+
+
+@pytest.mark.parametrize("case", list(PLAN_CASES))
+def test_flash_plan_fetches_no_dead_tile(case):
+    """A live tile fetches its own kv block; a dead one names the block its
+    q block already holds: the last live one past the causal frontier, the
+    first live one behind the window.  So K/V copies are counted from the
+    live tiles alone."""
+    B, H, Hkv, Sq, Sk, D, Dv, causal, window, q_offset, blocks = \
+        PLAN_CASES[case]
+    bq, bk = blocks or (None, None)
+    plan = flash_plan(B, H, Hkv, Sq, Sk, D, Dv, causal=causal, window=window,
+                      q_offset=q_offset, block_q=bq, block_k=bk)
+    bq, bk = plan.block_q, plan.block_k
+    live, _ = _brute_tiles(Sq, Sk, causal, window, q_offset, bq, bk)
+    nq, nk = live.shape
+    fetched = []
+    for iq in range(nq):
+        cols = np.flatnonzero(live[iq])
+        for ik in range(nk):
+            got = int(_kv_block(iq, ik, bq=bq, bk=bk, nk=nk,
+                                q_offset=q_offset, q_end=q_offset + Sq - 1,
+                                causal=causal, window=window, xp=np))
+            if live[iq, ik]:
+                assert got == ik, (iq, ik, got)
+            elif ik > cols[-1]:
+                assert got == cols[-1], (iq, ik, got)
+            else:
+                assert got == cols[0], (iq, ik, got)
+            fetched.append(got)
+    copies = 1 + sum(a != b for a, b in zip(fetched, fetched[1:]))
+    assert copies <= live.sum()
+    assert plan.kv_bytes == B * Hkv * copies * bk * (D + Dv) * 4
+
+
+def test_flash_plan_cell_shapes():
+    """The LM cells' calls: qwen3-0.6b at 8 x 1024 and DeepSeek-V2-Lite's
+    latent attention at 4 x 4096, against 8,192 and 65,536 grid steps of
+    128 x 128 tiles one q head at a time."""
+    qwen3 = flash_plan(8, 16, 8, 1024, 1024, 128, 128)
+    assert (qwen3.block_q, qwen3.block_k) == (512, 512)
+    assert qwen3.grid_steps == 256 <= 8192 // 8
+    assert qwen3.kv_bytes == 64 * 2**20             # 1.07 GB at 128 x 128
+    assert flash_plan(4, 16, 16, 4096, 4096, 192, 128).grid_steps == 4096
+
+
+@pytest.mark.parametrize("Sk", [1, 100, 300, 4096, 5000])
+def test_flash_plan_decode_no_worse_than_128_blocks(Sk):
+    plan = flash_plan(2, 16, 8, 1, Sk, 128, 128, q_offset=Sk - 1)
+    assert plan.block_q == 1
+    assert plan.grid_steps <= 2 * 16 * -(-Sk // 128)
+    pad_at_128 = Sk if Sk <= 128 else -(-Sk // 128) * 128
+    assert plan.grid[3] * plan.block_k <= pad_at_128
 
 
 @pytest.mark.parametrize("Dv", [16, 48])

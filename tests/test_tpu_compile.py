@@ -119,6 +119,22 @@ def test_flash_attention_latent_head_dims_compile(one_chip):
              one_chip, (qk, F32), (qk, F32), (v, F32))
 
 
+@pytest.mark.parametrize("qk,kv,vv,scale", [
+    ((8, 16, 1024, 128), (8, 8, 1024, 128), (8, 8, 1024, 128), None),
+    ((4, 16, 4096, 192), (4, 16, 4096, 192), (4, 16, 4096, 128), 0.11472),
+], ids=["qwen3_8x1024", "latent_4x4096"])
+def test_flash_attention_planned_blocks_compile_at_cell_shapes(
+        one_chip, qk, kv, vv, scale):
+    """The planned blocks at the LM cells' own shapes pass Mosaic's tiling
+    and VMEM checks, as one kernel named after its jitted wrapper."""
+    text = _compile(lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=True, scale=scale), one_chip, (qk, F32), (kv, F32),
+        (vv, F32))
+    calls = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 1 and "%flash_attention_pallas" in calls[0], calls
+
+
 # reverse mode through ops' custom VJPs, taking the TPU branch: the forward
 # is the compiled kernel, the backward the XLA VJP of the jnp oracle
 
