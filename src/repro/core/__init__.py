@@ -22,16 +22,11 @@ from .rk import (ON_FAILURE_POLICIES, AdaptiveConfig, AdaptiveSolution,
                  BatchedAdaptiveSolution, SlicedField, apply_on_failure,
                  apply_on_failure_lanes, hermite_observe, lane_count,
                  rk_solve_adaptive, rk_solve_adaptive_batched,
-                 rk_solve_adaptive_batched_saveat_stacked,
-                 rk_solve_adaptive_saveat, rk_solve_adaptive_saveat_stacked,
-                 rk_solve_fixed, rk_stages, rk_step, tree_scale_add)
+                 rk_solve_fixed, rk_solve_segment, rk_solve_segments,
+                 rk_stages, rk_step, tree_scale_add)
 from .stepper import (AdaptiveStepper, FixedSolverState, FixedStepper,
                       SolverState)
-from .symplectic import (odeint_symplectic, odeint_symplectic_adaptive,
-                         odeint_symplectic_adaptive_batched,
-                         odeint_symplectic_saveat,
-                         odeint_symplectic_saveat_adaptive,
-                         odeint_symplectic_saveat_adaptive_batched,
+from .symplectic import (symplectic_replay, symplectic_solve,
                          symplectic_step_adjoint,
                          symplectic_step_adjoint_lanes)
 from .adjoint import (odeint_adjoint, odeint_adjoint_adaptive,
@@ -52,16 +47,12 @@ __all__ = [
     "COMBINE_BACKENDS", "StageCombiner", "get_combiner", "alloc_stages",
     "set_stage", "stage_prefix", "stage_suffix",
     "rk_solve_fixed", "rk_solve_adaptive", "rk_solve_adaptive_batched",
-    "rk_solve_adaptive_saveat", "rk_solve_adaptive_saveat_stacked",
-    "rk_solve_adaptive_batched_saveat_stacked", "lane_count",
+    "rk_solve_segment", "rk_solve_segments", "lane_count",
     "rk_step", "rk_stages", "tree_scale_add", "apply_on_failure",
     "SlicedField",
     "apply_on_failure_lanes",
     "SolverState", "FixedSolverState", "AdaptiveStepper", "FixedStepper",
-    "hermite_observe", "odeint_symplectic", "odeint_symplectic_adaptive",
-    "odeint_symplectic_adaptive_batched",
-    "odeint_symplectic_saveat", "odeint_symplectic_saveat_adaptive",
-    "odeint_symplectic_saveat_adaptive_batched",
+    "hermite_observe", "symplectic_solve", "symplectic_replay",
     "symplectic_step_adjoint", "symplectic_step_adjoint_lanes",
     "odeint_adjoint", "odeint_adjoint_adaptive",
     "odeint_adjoint_adaptive_batched",

@@ -88,16 +88,9 @@ from .adjoint import (odeint_adjoint, odeint_adjoint_adaptive,
 from .backprop import odeint_backprop, odeint_remat_solve, odeint_remat_step
 from .combine import resolve_backend
 from .rk import (AdaptiveConfig, VectorField, apply_on_failure,
-                 apply_on_failure_lanes, hermite_observe, lane_count,
-                 rk_solve_adaptive, rk_solve_adaptive_batched,
-                 rk_solve_adaptive_batched_saveat_stacked,
-                 rk_solve_adaptive_saveat_stacked, rk_solve_fixed,
-                 segment_starts)
-from .symplectic import (odeint_symplectic, odeint_symplectic_adaptive,
-                         odeint_symplectic_adaptive_batched,
-                         odeint_symplectic_saveat,
-                         odeint_symplectic_saveat_adaptive,
-                         odeint_symplectic_saveat_adaptive_batched)
+                 hermite_observe, lane_count, rk_solve_adaptive,
+                 rk_solve_segment, rk_solve_segments, segment_starts)
+from .symplectic import symplectic_solve
 from .tableau import ButcherTableau, get_tableau
 
 Pytree = Any
@@ -211,17 +204,34 @@ class Solution:
 # ---------------------------------------------------------------------------
 
 class _Ctx:
-    """Static per-solve context handed to every strategy hook."""
-    __slots__ = ("f", "tab", "n_steps", "adaptive", "backend")
+    """Static per-solve context handed to every strategy hook.
+
+    ``n_steps`` (fixed grid) xor ``adaptive`` (an ``AdaptiveConfig``) is
+    the stepping; ``saveat`` is the observation kind ("t1", "ts" or
+    "dense"); ``lanes`` is the lane count under ``batch_axis=0`` (the
+    shard-local count on a mesh) and None for one trajectory."""
+    __slots__ = ("f", "tab", "n_steps", "adaptive", "backend", "saveat",
+                 "lanes")
 
     def __init__(self, f: VectorField, tab: ButcherTableau,
                  n_steps: Optional[int], adaptive: Optional[AdaptiveConfig],
-                 backend: str):
+                 backend: str, saveat: str = "t1",
+                 lanes: Optional[int] = None):
         self.f = f
         self.tab = tab
         self.n_steps = n_steps
         self.adaptive = adaptive
         self.backend = backend
+        self.saveat = saveat
+        self.lanes = lanes
+
+    @property
+    def stepping(self) -> Union[int, AdaptiveConfig]:
+        return self.n_steps if self.adaptive is None else self.adaptive
+
+    @property
+    def dense(self) -> bool:
+        return self.saveat == "dense"
 
 
 def _segmented(solve_one: Callable, x0, t0, ts):
@@ -238,6 +248,62 @@ def _segmented(solve_one: Callable, x0, t0, ts):
     return obs
 
 
+def _counters(sol, segmented: bool):
+    """``(stats, success)`` of a stepper solution: per segment summed when
+    ``segmented`` (a leading segment axis), per lane when lane-batched."""
+    stats = {"n_steps": sol.n_accepted, "n_fevals": sol.n_fevals,
+             "n_attempts": sol.n_attempts}
+    if not segmented:
+        return stats, sol.succeeded
+    return ({k: jnp.sum(v, axis=0) for k, v in stats.items()},
+            jnp.all(sol.succeeded, axis=0))
+
+
+def _fixed_stats(tab: ButcherTableau, n_steps: int, n_segments: int,
+                 lanes: Optional[int] = None):
+    """Fixed-grid stats are exact static counts: the drivers skip the
+    embedded error estimate, so the cost is exactly s f-evals per step.
+    With ``lanes`` (batch_axis=0) the counts broadcast per lane — every
+    lane takes the same deterministic grid."""
+    total = n_segments * n_steps
+    fevals = total * tab.s
+    if lanes is None:
+        return ({"n_steps": jnp.int32(total),
+                 "n_fevals": jnp.int32(fevals),
+                 "n_attempts": jnp.int32(total)}, jnp.asarray(True))
+    return ({"n_steps": jnp.full((lanes,), total, jnp.int32),
+             "n_fevals": jnp.full((lanes,), fevals, jnp.int32),
+             "n_attempts": jnp.full((lanes,), total, jnp.int32)},
+            jnp.ones((lanes,), bool))
+
+
+def _solve_stats(ctx: _Ctx, x0, t0, tobs, params, threaded: bool):
+    """``(stats, success)`` of a ``t1``/``ts`` solve whose value came from
+    a driver that does not expose its counters.  A fixed grid's are static
+    counts.  An adaptive solve's come from replaying the controller once
+    under stop_gradient with the exact arguments of the value's forward
+    pass — the counters match the value solve bit-for-bit, and under jit
+    XLA CSE/DCE collapses the duplicate.  ``threaded`` says which ``ts``
+    sequence the value realized: the controller step threading across the
+    observation boundaries (``rk_solve_segments``), or restarting at each
+    (generic segmentation over the ``adaptive`` hook)."""
+    segmented = ctx.saveat == "ts"
+    if ctx.adaptive is None:
+        return _fixed_stats(ctx.tab, ctx.n_steps,
+                            tobs.shape[0] if segmented else 1, ctx.lanes)
+    x0 = jax.lax.stop_gradient(x0)
+    params = jax.lax.stop_gradient(params)
+    lanes = ctx.lanes is not None
+    if segmented:
+        _, sol = rk_solve_segments(ctx.f, ctx.tab, x0, t0, tobs, params,
+                                   ctx.adaptive, ctx.backend, lanes=lanes,
+                                   threaded=threaded)
+    else:
+        sol = rk_solve_segment(ctx.f, ctx.tab, x0, t0, tobs, params,
+                               ctx.adaptive, ctx.backend, lanes=lanes)
+    return _counters(sol, segmented)
+
+
 _FIXED_T1 = ("fixed", "t1")
 _FIXED_TS = ("fixed", "ts")
 _ADAPT_T1 = ("adaptive", "t1")
@@ -249,12 +315,22 @@ class GradientStrategy:
     """Base class for gradient strategies.
 
     A strategy declares its legal (stepping, saveat) cells in
-    ``capabilities`` and implements the value hooks for the cells it
-    supports; the SaveAt hooks default to generic checkpointed segmentation
-    over the plain solves, and the stats hooks default to a non-
-    differentiated controller replay — so a minimal new strategy is
-    ``name`` + ``capabilities`` + ``fixed`` (and ``adaptive`` if claimed).
-    Register it with ``@register_gradient``; ``solve`` needs no edits.
+    ``capabilities`` and implements the hooks for the cells it supports.
+    There are three:
+
+    * ``fixed(ctx, x0, t0, t1, params)`` and ``adaptive(...)`` — the
+      differentiable value of ONE segment [t0, t1] on a fixed grid or under
+      ``ctx.adaptive`` (on lanes when ``ctx.lanes`` is set);
+    * ``observe(ctx, x0, t0, tobs, params) -> (ys, stats, success)`` — the
+      whole solve: ``tobs`` is ``t1`` or the observation times ``ts`` as
+      ``ctx.saveat`` says.  The default chains the segment hooks by generic
+      checkpointed segmentation (the adaptive controller restarts at each
+      boundary) and reads the stats from ``_solve_stats``.  A strategy with
+      a whole-solve driver overrides it.
+
+    So a minimal new strategy is ``name`` + ``capabilities`` + ``fixed``
+    (and ``adaptive`` if claimed).  Register it with ``@register_gradient``;
+    ``solve`` needs no edits.
     """
     name: ClassVar[str]
     capabilities: ClassVar[FrozenSet[Tuple[str, str]]]
@@ -273,161 +349,28 @@ class GradientStrategy:
         fixed = frozenset(c for c in cls.capabilities if c[0] == "fixed")
         return fixed | cls.batched_capabilities
 
-    # -- value hooks --------------------------------------------------------
     def fixed(self, ctx: _Ctx, x0, t0, t1, params):
         raise NotImplementedError
 
     def adaptive(self, ctx: _Ctx, x0, t0, t1, params):
         raise NotImplementedError
 
-    def fixed_saveat(self, ctx: _Ctx, x0, t0, ts, params):
-        return _segmented(lambda x, a, b: self.fixed(ctx, x, a, b, params),
-                          x0, t0, ts)
+    def observe(self, ctx: _Ctx, x0, t0, tobs, params):
+        if ctx.dense:
+            # dense output has no segment form: a strategy claiming
+            # ('adaptive', 'dense') overrides observe
+            raise NotImplementedError
+        hook = self.fixed if ctx.adaptive is None else self.adaptive
 
-    def adaptive_saveat(self, ctx: _Ctx, x0, t0, ts, params):
-        return _segmented(
-            lambda x, a, b: self.adaptive(ctx, x, a, b, params), x0, t0, ts)
+        def one(x, a, b):
+            return hook(ctx, x, a, b, params)
 
-    # -- stats hooks (non-differentiated controller replays) ----------------
-    def adaptive_stats(self, ctx: _Ctx, x0, t0, t1, params):
-        """Counters of the realized adaptive solve.  Default: replay the
-        controller once under stop_gradient with the exact arguments every
-        driver's forward pass uses — the counters match the value solve
-        bit-for-bit, and under jit XLA CSE/DCE collapses the duplicate."""
-        sol = rk_solve_adaptive(ctx.f, ctx.tab, jax.lax.stop_gradient(x0),
-                                t0, t1, jax.lax.stop_gradient(params),
-                                ctx.adaptive, ctx.backend)
-        return ({"n_steps": sol.n_accepted, "n_fevals": sol.n_fevals,
-                 "n_attempts": sol.n_attempts}, sol.succeeded)
-
-    def adaptive_saveat_stats(self, ctx: _Ctx, x0, t0, ts, params):
-        """Default segmented replay RESTARTS the controller at every
-        observation boundary — exactly the step sequence the default
-        ``adaptive_saveat`` (generic segmentation over ``adaptive``)
-        realizes.  Strategies whose SaveAt drivers thread the controller
-        step across boundaries (symplectic, backprop) override this with
-        the threaded stacked replay so stats and value always describe the
-        SAME solve."""
-        cfg = ctx.adaptive
-        x0 = jax.lax.stop_gradient(x0)
-        params = jax.lax.stop_gradient(params)
-
-        def body(x, seg):
-            a, b = seg
-            sol = rk_solve_adaptive(ctx.f, ctx.tab, x, a, b, params, cfg,
-                                    ctx.backend)
-            x = apply_on_failure(sol.x_final, sol.succeeded, cfg.on_failure)
-            return x, (sol.n_accepted, sol.n_fevals, sol.n_attempts,
-                       sol.succeeded)
-
-        _, (na, nf, nt, ok) = jax.lax.scan(body, x0,
-                                           (segment_starts(t0, ts), ts))
-        return ({"n_steps": jnp.sum(na), "n_fevals": jnp.sum(nf),
-                 "n_attempts": jnp.sum(nt)}, jnp.all(ok))
-
-    # -- combined value+stats hooks (what ``solve`` actually calls) ---------
-    def adaptive_with_stats(self, ctx: _Ctx, x0, t0, t1, params):
-        """Value + stats for an adaptive t1 solve.  Strategies whose value
-        driver already exposes the controller counters override this to a
-        single run (DirectBackprop); custom-VJP strategies keep the
-        default value-hook + replay pair."""
-        ys = self.adaptive(ctx, x0, t0, t1, params)
-        stats, success = self.adaptive_stats(ctx, x0, t0, t1, params)
-        return ys, stats, success
-
-    def adaptive_saveat_with_stats(self, ctx: _Ctx, x0, t0, ts, params):
-        ys = self.adaptive_saveat(ctx, x0, t0, ts, params)
-        stats, success = self.adaptive_saveat_stats(ctx, x0, t0, ts, params)
-        return ys, stats, success
-
-    def dense_saveat_with_stats(self, ctx: _Ctx, x0, t0, ts, params):
-        """Dense-output observation.  NOTE: unlike the plain value hooks
-        this returns the (ys, stats, success) triple — dense output and
-        its controller run are inseparable, so there is no value-only
-        form.  Unreachable unless the strategy claims ('adaptive',
-        'dense')."""
-        raise NotImplementedError
-
-    # -- batched hooks (masked per-lane adaptive control, batch_axis=0) -----
-    # Stats and success are PER LANE: (B,) int32 / bool arrays.
-    def adaptive_batched(self, ctx: _Ctx, x0, t0, t1, params):
-        raise NotImplementedError
-
-    def adaptive_saveat_batched(self, ctx: _Ctx, x0, t0, ts, params):
-        return _segmented(
-            lambda x, a, b: self.adaptive_batched(ctx, x, a, b, params),
-            x0, t0, ts)
-
-    def adaptive_batched_stats(self, ctx: _Ctx, x0, t0, t1, params):
-        """Per-lane counters of the realized batched solve (stop_gradient
-        controller replay, exactly like ``adaptive_stats``)."""
-        sol = rk_solve_adaptive_batched(
-            ctx.f, ctx.tab, jax.lax.stop_gradient(x0), t0, t1,
-            jax.lax.stop_gradient(params), ctx.adaptive, ctx.backend)
-        return ({"n_steps": sol.n_accepted, "n_fevals": sol.n_fevals,
-                 "n_attempts": sol.n_attempts}, sol.succeeded)
-
-    def adaptive_saveat_batched_stats(self, ctx: _Ctx, x0, t0, ts, params):
-        """Restart-per-segment batched replay, matching the step sequence
-        the default ``adaptive_saveat_batched`` (generic segmentation over
-        ``adaptive_batched``) realizes.  Strategies whose batched SaveAt
-        drivers thread the per-lane controller step across boundaries
-        override with the threaded stacked replay."""
-        cfg = ctx.adaptive
-        x0 = jax.lax.stop_gradient(x0)
-        params = jax.lax.stop_gradient(params)
-
-        def body(x, seg):
-            a, b = seg
-            sol = rk_solve_adaptive_batched(ctx.f, ctx.tab, x, a, b, params,
-                                            cfg, ctx.backend)
-            x = apply_on_failure_lanes(sol.x_final, sol.succeeded,
-                                       cfg.on_failure)
-            return x, (sol.n_accepted, sol.n_fevals, sol.n_attempts,
-                       sol.succeeded)
-
-        _, (na, nf, nt, ok) = jax.lax.scan(body, x0,
-                                           (segment_starts(t0, ts), ts))
-        return ({"n_steps": jnp.sum(na, axis=0),
-                 "n_fevals": jnp.sum(nf, axis=0),
-                 "n_attempts": jnp.sum(nt, axis=0)}, jnp.all(ok, axis=0))
-
-    def adaptive_batched_with_stats(self, ctx: _Ctx, x0, t0, t1, params):
-        ys = self.adaptive_batched(ctx, x0, t0, t1, params)
-        stats, success = self.adaptive_batched_stats(ctx, x0, t0, t1, params)
-        return ys, stats, success
-
-    def adaptive_saveat_batched_with_stats(self, ctx: _Ctx, x0, t0, ts,
-                                           params):
-        ys = self.adaptive_saveat_batched(ctx, x0, t0, ts, params)
-        stats, success = self.adaptive_saveat_batched_stats(
-            ctx, x0, t0, ts, params)
-        return ys, stats, success
-
-
-def _threaded_saveat_batched_stats(ctx: _Ctx, x0, t0, ts, params):
-    """Per-lane stats replay for batched SaveAt drivers that thread each
-    lane's controller step across observation boundaries."""
-    _, sols = rk_solve_adaptive_batched_saveat_stacked(
-        ctx.f, ctx.tab, jax.lax.stop_gradient(x0), t0, ts,
-        jax.lax.stop_gradient(params), ctx.adaptive, ctx.backend)
-    return ({"n_steps": jnp.sum(sols.n_accepted, axis=0),
-             "n_fevals": jnp.sum(sols.n_fevals, axis=0),
-             "n_attempts": jnp.sum(sols.n_attempts, axis=0)},
-            jnp.all(sols.succeeded, axis=0))
-
-
-def _threaded_saveat_stats(ctx: _Ctx, x0, t0, ts, params):
-    """Stats replay for SaveAt drivers that THREAD the controller step
-    across observation boundaries (the stacked-scan segmentation the
-    symplectic and backprop drivers use)."""
-    _, sols = rk_solve_adaptive_saveat_stacked(
-        ctx.f, ctx.tab, jax.lax.stop_gradient(x0), t0, ts,
-        jax.lax.stop_gradient(params), ctx.adaptive, ctx.backend)
-    return ({"n_steps": jnp.sum(sols.n_accepted),
-             "n_fevals": jnp.sum(sols.n_fevals),
-             "n_attempts": jnp.sum(sols.n_attempts)},
-            jnp.all(sols.succeeded))
+        if ctx.saveat == "t1":
+            ys = one(x0, t0, tobs)
+        else:
+            ys = _segmented(one, x0, t0, tobs)
+        return (ys,) + _solve_stats(ctx, x0, t0, tobs, params,
+                                    threaded=False)
 
 
 GRADIENT_REGISTRY: Dict[str, Type[GradientStrategy]] = {}
@@ -472,36 +415,13 @@ class SymplecticAdjoint(GradientStrategy):
     batched_capabilities: ClassVar[FrozenSet] = frozenset(
         {_ADAPT_T1, _ADAPT_TS})
 
-    def fixed(self, ctx, x0, t0, t1, params):
-        return odeint_symplectic(ctx.f, ctx.tab, ctx.n_steps, ctx.backend,
-                                 x0, t0, t1, params)
-
-    def adaptive(self, ctx, x0, t0, t1, params):
-        return odeint_symplectic_adaptive(ctx.f, ctx.tab, ctx.adaptive,
-                                          ctx.backend, x0, t0, t1, params)
-
-    def fixed_saveat(self, ctx, x0, t0, ts, params):
-        return odeint_symplectic_saveat(ctx.f, ctx.tab, ctx.n_steps,
-                                        ctx.backend, x0, t0, ts, params)
-
-    def adaptive_saveat(self, ctx, x0, t0, ts, params):
-        return odeint_symplectic_saveat_adaptive(
-            ctx.f, ctx.tab, ctx.adaptive, ctx.backend, x0, t0, ts, params)
-
-    def adaptive_saveat_stats(self, ctx, x0, t0, ts, params):
-        return _threaded_saveat_stats(ctx, x0, t0, ts, params)
-
-    # batched: exact per-lane gradients replaying each lane's own grid
-    def adaptive_batched(self, ctx, x0, t0, t1, params):
-        return odeint_symplectic_adaptive_batched(
-            ctx.f, ctx.tab, ctx.adaptive, ctx.backend, x0, t0, t1, params)
-
-    def adaptive_saveat_batched(self, ctx, x0, t0, ts, params):
-        return odeint_symplectic_saveat_adaptive_batched(
-            ctx.f, ctx.tab, ctx.adaptive, ctx.backend, x0, t0, ts, params)
-
-    def adaptive_saveat_batched_stats(self, ctx, x0, t0, ts, params):
-        return _threaded_saveat_batched_stats(ctx, x0, t0, ts, params)
+    def observe(self, ctx, x0, t0, tobs, params):
+        ys = symplectic_solve(ctx.f, ctx.tab, ctx.stepping, ctx.backend,
+                              x0, t0, tobs, params,
+                              lanes=ctx.lanes is not None,
+                              segmented=ctx.saveat == "ts")
+        return (ys,) + _solve_stats(ctx, x0, t0, tobs, params,
+                                    threaded=True)
 
 
 @register_gradient
@@ -520,88 +440,35 @@ class DirectBackprop(GradientStrategy):
         return odeint_backprop(ctx.f, ctx.tab, ctx.n_steps, x0, t0, t1,
                                params, ctx.backend)
 
-    def adaptive(self, ctx, x0, t0, t1, params):
-        sol = rk_solve_adaptive(ctx.f, ctx.tab, x0, t0, t1, params,
-                                ctx.adaptive, ctx.backend)
-        return apply_on_failure(sol.x_final, sol.succeeded,
-                                ctx.adaptive.on_failure)
-
-    def adaptive_saveat(self, ctx, x0, t0, ts, params):
-        obs, _ = rk_solve_adaptive_saveat_stacked(
-            ctx.f, ctx.tab, x0, t0, ts, params, ctx.adaptive, ctx.backend)
-        return obs
-
-    # the value drivers above ARE the controller, so value and stats come
-    # from ONE run — no replay (this is also what keeps the
+    # adaptive: the value drivers ARE the controllers, so value and stats
+    # come from ONE run — no replay (this is also what keeps the
     # odeint_with_stats shim at its historical single-solve cost).
-    def adaptive_with_stats(self, ctx, x0, t0, t1, params):
-        sol = rk_solve_adaptive(ctx.f, ctx.tab, x0, t0, t1, params,
-                                ctx.adaptive, ctx.backend)
-        ys = apply_on_failure(sol.x_final, sol.succeeded,
-                              ctx.adaptive.on_failure)
-        return ys, {"n_steps": sol.n_accepted, "n_fevals": sol.n_fevals,
-                    "n_attempts": sol.n_attempts}, sol.succeeded
-
-    def adaptive_saveat_with_stats(self, ctx, x0, t0, ts, params):
-        obs, sols = rk_solve_adaptive_saveat_stacked(
-            ctx.f, ctx.tab, x0, t0, ts, params, ctx.adaptive, ctx.backend)
-        return obs, {"n_steps": jnp.sum(sols.n_accepted),
-                     "n_fevals": jnp.sum(sols.n_fevals),
-                     "n_attempts": jnp.sum(sols.n_attempts)}, \
-            jnp.all(sols.succeeded)
-
-    # solve() takes the single-run combined hook above; this override
-    # exists so the standalone stats hook ALSO describes the threaded
-    # sequence this strategy's adaptive_saveat realizes (the base default
-    # replays a restarting segmentation), keeping the hook family
-    # self-consistent for subclassers and direct callers.
-    def adaptive_saveat_stats(self, ctx, x0, t0, ts, params):
-        return _threaded_saveat_stats(ctx, x0, t0, ts, params)
-
-    # batched: the value drivers ARE the per-lane controllers — one run.
-    def adaptive_batched(self, ctx, x0, t0, t1, params):
-        sol = rk_solve_adaptive_batched(ctx.f, ctx.tab, x0, t0, t1, params,
-                                        ctx.adaptive, ctx.backend)
-        return apply_on_failure_lanes(sol.x_final, sol.succeeded,
-                                      ctx.adaptive.on_failure)
-
-    def adaptive_batched_with_stats(self, ctx, x0, t0, t1, params):
-        sol = rk_solve_adaptive_batched(ctx.f, ctx.tab, x0, t0, t1, params,
-                                        ctx.adaptive, ctx.backend)
-        ys = apply_on_failure_lanes(sol.x_final, sol.succeeded,
-                                    ctx.adaptive.on_failure)
-        return ys, {"n_steps": sol.n_accepted, "n_fevals": sol.n_fevals,
-                    "n_attempts": sol.n_attempts}, sol.succeeded
-
-    def adaptive_saveat_batched(self, ctx, x0, t0, ts, params):
-        obs, _ = rk_solve_adaptive_batched_saveat_stacked(
-            ctx.f, ctx.tab, x0, t0, ts, params, ctx.adaptive, ctx.backend)
-        return obs
-
-    def adaptive_saveat_batched_with_stats(self, ctx, x0, t0, ts, params):
-        obs, sols = rk_solve_adaptive_batched_saveat_stacked(
-            ctx.f, ctx.tab, x0, t0, ts, params, ctx.adaptive, ctx.backend)
-        return obs, {"n_steps": jnp.sum(sols.n_accepted, axis=0),
-                     "n_fevals": jnp.sum(sols.n_fevals, axis=0),
-                     "n_attempts": jnp.sum(sols.n_attempts, axis=0)}, \
-            jnp.all(sols.succeeded, axis=0)
-
-    def adaptive_saveat_batched_stats(self, ctx, x0, t0, ts, params):
-        return _threaded_saveat_batched_stats(ctx, x0, t0, ts, params)
-
-    def dense_saveat_with_stats(self, ctx, x0, t0, ts, params):
-        # ONE unsegmented solve + Hermite interpolation: value and stats
-        # come from the same controller run (2 extra f-evals per
-        # observation for the endpoint slopes).
+    def observe(self, ctx, x0, t0, tobs, params):
         cfg = ctx.adaptive
-        sol = rk_solve_adaptive(ctx.f, ctx.tab, x0, t0, ts[-1], params,
-                                cfg, ctx.backend)
-        obs = hermite_observe(ctx.f, ctx.tab, sol, params, ts, ctx.backend)
-        ys = apply_on_failure(obs, sol.succeeded, cfg.on_failure)
-        stats = {"n_steps": sol.n_accepted,
-                 "n_fevals": sol.n_fevals + 2 * ts.shape[0],
-                 "n_attempts": sol.n_attempts}
-        return ys, stats, sol.succeeded
+        if cfg is None:
+            return super().observe(ctx, x0, t0, tobs, params)
+        if ctx.dense:
+            # ONE unsegmented solve + Hermite interpolation: value and
+            # stats come from the same controller run (2 extra f-evals per
+            # observation for the endpoint slopes).
+            sol = rk_solve_adaptive(ctx.f, ctx.tab, x0, t0, tobs[-1], params,
+                                    cfg, ctx.backend)
+            obs = hermite_observe(ctx.f, ctx.tab, sol, params, tobs,
+                                  ctx.backend)
+            ys = apply_on_failure(obs, sol.succeeded, cfg.on_failure)
+            stats = {"n_steps": sol.n_accepted,
+                     "n_fevals": sol.n_fevals + 2 * tobs.shape[0],
+                     "n_attempts": sol.n_attempts}
+            return ys, stats, sol.succeeded
+        lanes = ctx.lanes is not None
+        if ctx.saveat == "ts":
+            ys, sol = rk_solve_segments(ctx.f, ctx.tab, x0, t0, tobs, params,
+                                        cfg, ctx.backend, lanes=lanes)
+            return (ys,) + _counters(sol, True)
+        sol = rk_solve_segment(ctx.f, ctx.tab, x0, t0, tobs, params, cfg,
+                               ctx.backend, lanes=lanes)
+        ys = apply_on_failure(sol.x_final, sol.succeeded, cfg.on_failure)
+        return (ys,) + _counters(sol, False)
 
 
 @register_gradient
@@ -669,22 +536,16 @@ class ContinuousAdjoint(GradientStrategy):
                               x0, t0, t1, params)
 
     def adaptive(self, ctx, x0, t0, t1, params):
-        return odeint_adjoint_adaptive(
-            ctx.f, ctx.tab, ctx.adaptive,
-            self.bwd_adaptive or ctx.adaptive, ctx.backend,
-            x0, t0, t1, params)
-
-    def adaptive_batched(self, ctx, x0, t0, t1, params):
-        # per-lane forward AND backward grids; the backward augmented state
-        # carries a per-lane grad-theta accumulator — O(B L) memory
-        # (core/adjoint.py, docs/batching.md).
-        return odeint_adjoint_adaptive_batched(
-            ctx.f, ctx.tab, ctx.adaptive,
-            self.bwd_adaptive or ctx.adaptive, ctx.backend,
-            x0, t0, t1, params)
-    # SaveAt value AND stats both come from the base class (batched and
-    # not): generic restart-per-segment segmentation + the matching
-    # restart replay.
+        # on lanes: per-lane forward AND backward grids; the backward
+        # augmented state carries a per-lane grad-theta accumulator —
+        # O(B L) memory (core/adjoint.py, docs/batching.md).
+        drive = (odeint_adjoint_adaptive if ctx.lanes is None
+                 else odeint_adjoint_adaptive_batched)
+        return drive(ctx.f, ctx.tab, ctx.adaptive,
+                     self.bwd_adaptive or ctx.adaptive, ctx.backend,
+                     x0, t0, t1, params)
+    # SaveAt value AND stats both come from the base ``observe``: generic
+    # restart-per-segment segmentation + the matching restart replay.
 
 
 # ---------------------------------------------------------------------------
@@ -712,7 +573,7 @@ def batched_capability_matrix() -> Dict[str, Dict[Tuple[str, str], bool]]:
 def mesh_capability_matrix() -> Dict[str, Dict[Tuple[str, str], bool]]:
     """Same table for ``solve(..., batch_axis=0, mesh=...)``: the batched
     cells restricted to t1|ts saveat.  The mesh path shard_maps the SAME
-    batched hooks (``fixed``/``fixed_saveat``/``adaptive_*_with_stats``),
+    solve body (the strategy's ``observe``) as the unsharded batched solve,
     so every batched t1/ts cell is mesh-legal; dense output is not wired
     through shard_map."""
     return {name: {cell: ok and cell[1] in ("t1", "ts")
@@ -739,24 +600,6 @@ def _check_capability(gradient: GradientStrategy, stepping_kind: str,
 # ---------------------------------------------------------------------------
 # solve
 # ---------------------------------------------------------------------------
-
-def _fixed_stats(tab: ButcherTableau, n_steps: int, n_segments: int,
-                 lanes: Optional[int] = None):
-    """Fixed-grid stats are exact static counts: the drivers skip the
-    embedded error estimate, so the cost is exactly s f-evals per step.
-    With ``lanes`` (batch_axis=0) the counts broadcast per lane — every
-    lane takes the same deterministic grid."""
-    total = n_segments * n_steps
-    fevals = total * tab.s
-    if lanes is None:
-        return ({"n_steps": jnp.int32(total),
-                 "n_fevals": jnp.int32(fevals),
-                 "n_attempts": jnp.int32(total)}, jnp.asarray(True))
-    return ({"n_steps": jnp.full((lanes,), total, jnp.int32),
-             "n_fevals": jnp.full((lanes,), fevals, jnp.int32),
-             "n_attempts": jnp.full((lanes,), total, jnp.int32)},
-            jnp.ones((lanes,), bool))
-
 
 def solve(f: VectorField, x0, params, *,
           saveat: Optional[SaveAt] = None,
@@ -833,121 +676,64 @@ def solve(f: VectorField, x0, params, *,
                 f"AdaptiveConfig; got {type(stepping).__name__}")
 
         _check_capability(gradient, stepping_kind, saveat.kind, batched)
-        t0 = jnp.asarray(t0, dtype=jnp.result_type(float))
-        ctx = _Ctx(f, tab, n_steps, adaptive, backend)
-
         if mesh is None and sharding is not None:
             raise ValueError("solve(sharding=...) requires mesh=: the params "
                              "placement only means something on a mesh")
-        if mesh is not None:
-            if not batched:
-                raise ValueError(
-                    "solve(mesh=...) shards the lane axis over the mesh's "
-                    "data axes: pass batch_axis=0 (a single trajectory has "
-                    "no lane axis to shard — see docs/parallel.md)")
-            return _solve_sharded(gradient, ctx, tab, n_steps,
-                                  stepping_kind, saveat, x0, t0, params,
-                                  lanes, mesh, sharding)
-
+        if mesh is not None and not batched:
+            raise ValueError(
+                "solve(mesh=...) shards the lane axis over the mesh's "
+                "data axes: pass batch_axis=0 (a single trajectory has "
+                "no lane axis to shard — see docs/parallel.md)")
+        t0 = jnp.asarray(t0, dtype=jnp.result_type(float))
         if saveat.kind == "t1":
-            t1 = jnp.asarray(saveat.t1, dtype=t0.dtype)
-            if stepping_kind == "fixed":
-                # the fixed grid is state-independent: the plain driver IS
-                # the per-lane solve, only the stats shapes change.
-                ys = gradient.fixed(ctx, x0, t0, t1, params)
-                stats, success = _fixed_stats(tab, n_steps, 1, lanes)
-            elif batched:
-                ys, stats, success = gradient.adaptive_batched_with_stats(
-                    ctx, x0, t0, t1, params)
-            else:
-                ys, stats, success = gradient.adaptive_with_stats(
-                    ctx, x0, t0, t1, params)
-            return Solution(ys=ys, final_state=ys, stats=stats,
-                            success=success)
+            tobs = jnp.asarray(saveat.t1, dtype=t0.dtype)
+        else:
+            tobs = _as_ts(saveat.ts, t0.dtype, t0)
 
-        ts = _as_ts(saveat.ts, t0.dtype, t0)
-        if saveat.kind == "ts":
-            if stepping_kind == "fixed":
-                ys = gradient.fixed_saveat(ctx, x0, t0, ts, params)
-                stats, success = _fixed_stats(tab, n_steps, ts.shape[0],
-                                              lanes)
-            elif batched:
-                ys, stats, success = \
-                    gradient.adaptive_saveat_batched_with_stats(
-                        ctx, x0, t0, ts, params)
-            else:
-                ys, stats, success = gradient.adaptive_saveat_with_stats(
-                    ctx, x0, t0, ts, params)
-        else:  # dense
-            ys, stats, success = gradient.dense_saveat_with_stats(
-                ctx, x0, t0, ts, params)
+        # The one solve body, run as it is or shard-locally on a mesh.
+        def body(x0_, params_, field=f):
+            ctx = _Ctx(field, tab, n_steps, adaptive, backend, saveat.kind,
+                       lane_count(x0_) if batched else None)
+            return gradient.observe(ctx, x0_, t0, tobs, params_)
 
-        final = jax.tree_util.tree_map(lambda l: l[-1], ys)
-        return Solution(ys=ys, final_state=final, stats=stats, success=success)
+        if mesh is None:
+            ys, stats, success = body(x0, params)
+        else:
+            ys, stats, success = _solve_sharded(
+                body, f, x0, params, lanes, mesh, sharding,
+                ys_lane_axis=0 if saveat.kind == "t1" else 1)
+        final = (ys if saveat.kind == "t1"
+                 else jax.tree_util.tree_map(lambda l: l[-1], ys))
+        return Solution(ys=ys, final_state=final, stats=stats,
+                        success=success)
 
 
-def _solve_sharded(gradient: GradientStrategy, ctx: _Ctx,
-                   tab: ButcherTableau, n_steps: Optional[int],
-                   stepping_kind: str, saveat: SaveAt, x0, t0, params,
-                   lanes: int, mesh, sharding) -> Solution:
-    """The mesh path of ``solve``: run the SAME dispatch as the unsharded
-    batched solve, but as a shard-local body under ``shard_map`` — each
+def _solve_sharded(body: Callable, f: VectorField, x0, params, lanes: int,
+                   mesh, sharding, ys_lane_axis: int):
+    """The mesh path of ``solve``: the SAME solve ``body`` as the
+    unsharded batched solve, run shard-locally under ``shard_map`` — each
     shard solves its contiguous lane block exactly as a single-device call
-    would (bitwise: values, per-lane stats, grids, h carries).  Lives here
-    rather than in ``repro.parallel`` so the dispatch stays next to the
-    unsharded branch it must mirror; the mesh mechanics (lane-axis
-    selection, specs, load stats) come from ``repro.parallel.solve``.
+    would (bitwise: values, per-lane stats, grids, h carries).  The mesh
+    mechanics (lane-axis selection, specs, load stats) come from
+    ``repro.parallel.solve``; ``ys_lane_axis`` is 1 for the time-major
+    SaveAt stacks.
     """
     from ..parallel import solve as _pps  # parallel imports core: lazy
     axes = _pps.lane_axes(mesh, lanes, require=True)
     n_shards = _pps.shard_count(mesh, axes)
-    lanes_local = lanes // n_shards
     # rank-0 param leaves stay lifted to (1,) through the whole shard-local
     # driver (they are saved as custom_vjp residuals, and jax 0.4.37's
     # shard_map transpose cannot handle rank-0 residuals/inputs); only the
     # user field sees the original scalars.
     params, _restore, _lifted = _pps.lift_scalar_params(params)
     if _lifted:
-        _f = ctx.f
-        ctx = _Ctx(lambda x, t, p: _f(x, t, _restore(p)), ctx.tab,
-                   ctx.n_steps, ctx.adaptive, ctx.backend)
-    pspec = _pps.resolve_param_specs(params, mesh, sharding)
-
-    if saveat.kind == "t1":
-        t1 = jnp.asarray(saveat.t1, dtype=t0.dtype)
-        if stepping_kind == "fixed":
-            def body(x0_, params_):
-                ys = gradient.fixed(ctx, x0_, t0, t1, params_)
-                stats, success = _fixed_stats(tab, n_steps, 1, lanes_local)
-                return ys, stats, success
-        else:
-            def body(x0_, params_):
-                return gradient.adaptive_batched_with_stats(
-                    ctx, x0_, t0, t1, params_)
-        ys, stats, success = _pps.sharded_solve_triple(
-            body, mesh, axes, x0, params, params_spec=pspec, ys_lane_axis=0)
-        stats = _pps.with_shard_load_stats(stats, n_shards)
-        return Solution(ys=ys, final_state=ys, stats=stats, success=success)
-
-    if saveat.kind != "ts":
-        # unreachable today (_check_capability rejects batched dense), but
-        # the mesh path must never silently fall through to a new kind.
-        raise ValueError(
-            f"solve(mesh=...) supports saveat t1|ts; got {saveat.kind!r}")
-    ts = _as_ts(saveat.ts, t0.dtype, t0)
-    if stepping_kind == "fixed":
-        def body(x0_, params_):
-            ys = gradient.fixed_saveat(ctx, x0_, t0, ts, params_)
-            stats, success = _fixed_stats(tab, n_steps, ts.shape[0],
-                                          lanes_local)
-            return ys, stats, success
+        def local(x0_, params_):
+            return body(x0_, params_,
+                        field=lambda x, t, p: f(x, t, _restore(p)))
     else:
-        def body(x0_, params_):
-            return gradient.adaptive_saveat_batched_with_stats(
-                ctx, x0_, t0, ts, params_)
-    # SaveAt stacks are time-major: lanes live on axis 1 of the ys leaves.
+        local = body
+    pspec = _pps.resolve_param_specs(params, mesh, sharding)
     ys, stats, success = _pps.sharded_solve_triple(
-        body, mesh, axes, x0, params, params_spec=pspec, ys_lane_axis=1)
-    stats = _pps.with_shard_load_stats(stats, n_shards)
-    final = jax.tree_util.tree_map(lambda l: l[-1], ys)
-    return Solution(ys=ys, final_state=final, stats=stats, success=success)
+        local, mesh, axes, x0, params, params_spec=pspec,
+        ys_lane_axis=ys_lane_axis)
+    return ys, _pps.with_shard_load_stats(stats, n_shards), success

@@ -1,6 +1,6 @@
 """Explicit Runge-Kutta integration over arbitrary pytree states.
 
-Three drivers, all thin loops over the stepper state machine in
+The drivers are thin loops over the stepper state machine in
 core/stepper.py (``init_state -> advance* -> finalize``):
 
   * ``rk_solve_fixed``    — N equal steps: a ``FixedStepper`` run as one
@@ -17,6 +17,12 @@ core/stepper.py (``init_state -> advance* -> finalize``):
   * ``rk_solve_adaptive_batched`` — B independent trajectories, one
                             while_loop, masked per-lane control: the SAME
                             stepper with a lane-batched ``SolverState``.
+  * ``rk_solve_segment``  — any of the three, chosen by the stepping (an
+                            ``int`` or an ``AdaptiveConfig``) and ``lanes``.
+  * ``rk_solve_segments`` — the solve observed at times ``ts``: one
+                            ``rk_solve_segment`` per segment inside ONE
+                            lax.scan, the adaptive controller step threading
+                            across the boundaries.
 
 All record the step checkpoints {x_n, t_n, h_n} that Algorithm 1 of the
 paper retains; computation graphs are never part of the residuals (the
@@ -153,6 +159,25 @@ def rk_solve_fixed(f: VectorField, tab: ButcherTableau, x0, t0, t1,
     return stepper.finalize(state)
 
 
+def rk_solve_segment(f: VectorField, tab: ButcherTableau, x0, t0, t1,
+                     params, stepping, combine_backend: str = "auto", *,
+                     lanes: bool = False, h0=None):
+    """One solve of [t0, t1]: a ``FixedStepper`` for an ``int`` stepping
+    (N equal steps), else an ``AdaptiveStepper`` with the ``AdaptiveConfig``
+    — on a lane-batched state when ``lanes`` (lane axis 0 of every ``x0``
+    leaf; a fixed grid ignores it, since the grid cannot depend on the
+    state).  ``h0`` seeds the adaptive controller (see
+    ``rk_solve_adaptive``).  Returns the stepper's solution tuple with its
+    raw ``x_final``: the ``on_failure`` policy is the caller's."""
+    if not isinstance(stepping, AdaptiveConfig):
+        return rk_solve_fixed(f, tab, x0, t0, t1, stepping, params,
+                              combine_backend)
+    stepper = AdaptiveStepper(f, tab, stepping, combine_backend)
+    state = stepper.init_state(x0, t0, t1, h0,
+                               lanes=lane_count(x0) if lanes else None)
+    return stepper.finalize(stepper.run(state, params))
+
+
 # ---------------------------------------------------------------------------
 # Adaptive stepping (PI controller), bounded buffer of accepted checkpoints.
 # ---------------------------------------------------------------------------
@@ -179,9 +204,8 @@ def rk_solve_adaptive(f: VectorField, tab: ButcherTableau, x0, t0, t1,
     rule (clamp, PI factor, commit, budgets) lives in ``advance`` and is
     shared verbatim with the batched driver and the serve engine.
     """
-    stepper = AdaptiveStepper(f, tab, cfg, combine_backend)
-    state = stepper.init_state(x0, t0, t1, h0)
-    return stepper.finalize(stepper.run(state, params))
+    return rk_solve_segment(f, tab, x0, t0, t1, params, cfg,
+                            combine_backend, h0=h0)
 
 
 def _raise_on_failure_cb(ok):
@@ -251,42 +275,8 @@ def rk_solve_adaptive_batched(f: VectorField, tab: ButcherTableau, x0,
     single-trajectory solve of lane b to rounding (tests/test_batch.py).
     ``t0``/``t1``/``h0`` may be scalars (shared) or (B,) per-lane arrays.
     """
-    B = lane_count(x0)
-    stepper = AdaptiveStepper(f, tab, cfg, combine_backend)
-    state = stepper.init_state(x0, t0, t1, h0, lanes=B)
-    return stepper.finalize(stepper.run(state, params))
-
-
-def rk_solve_adaptive_batched_saveat_stacked(
-        f: VectorField, tab: ButcherTableau, x0, t0, ts: jnp.ndarray,
-        params, cfg: AdaptiveConfig, combine_backend: str = "auto"):
-    """Batched analogue of ``rk_solve_adaptive_saveat_stacked``: one scanned
-    segment chain, per-lane controller state ``(x, h_final)`` threading
-    across every observation boundary (each lane's landing step stays
-    unclamped in ITS carry).  Observation times are shared across lanes.
-    A lane whose segment fails is poisoned per ``cfg.on_failure`` without
-    touching its batchmates, and the poison propagates to that lane's later
-    observations.  Returns (obs, sols) with a leading len(ts) segment axis
-    on every ``BatchedAdaptiveSolution`` field.
-    """
-    dtype = jnp.result_type(float)
-    ts = jnp.asarray(ts, dtype)
-    B = lane_count(x0)
-    t_starts = segment_starts(t0, ts)
-
-    def body(carry, seg):
-        x, h = carry
-        a, b = seg
-        sol = rk_solve_adaptive_batched(f, tab, x, a, b, params, cfg,
-                                        combine_backend, h0=h)
-        x = apply_on_failure_lanes(sol.x_final, sol.succeeded,
-                                   cfg.on_failure)
-        sol = sol._replace(x_final=x)
-        return (x, sol.h_final), sol
-
-    _, sols = jax.lax.scan(body, (x0, jnp.zeros((B,), dtype)),
-                           (t_starts, ts))
-    return sols.x_final, sols
+    return rk_solve_segment(f, tab, x0, t0, t1, params, cfg,
+                            combine_backend, lanes=True, h0=h0)
 
 
 # ---------------------------------------------------------------------------
@@ -303,62 +293,53 @@ def segment_starts(t0, ts: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([t0, ts[:-1]])
 
 
-def rk_solve_adaptive_saveat_stacked(f: VectorField, tab: ButcherTableau,
-                                     x0, t0, ts: jnp.ndarray, params,
-                                     cfg: AdaptiveConfig,
-                                     combine_backend: str = "auto"):
-    """Adaptive solve observed at the times ``ts`` by segmenting the solve.
+def rk_solve_segments(f: VectorField, tab: ButcherTableau, x0, t0,
+                      ts: jnp.ndarray, params, stepping,
+                      combine_backend: str = "auto", *, lanes: bool = False,
+                      threaded: bool = True):
+    """The solve observed at the times ``ts``, by segmenting it.
 
-    One adaptive sub-solve per segment [t0, ts[0]], [ts[0], ts[1]], ...; the
-    controller state threads across segments (each segment seeds its step
-    from the previous segment's unclamped ``h_final``, so landing exactly on
-    an observation time costs one clamped step, not a collapsed restart).
-    A failed segment poisons its state per ``cfg.on_failure`` and the
-    poison propagates to every later observation.
-
-    The segments run inside ONE ``lax.scan`` (every segment shares the
+    One ``rk_solve_segment`` per segment [t0, ts[0]], [ts[0], ts[1]], ...
+    inside ONE ``lax.scan`` (every segment shares the step count or the
     ``max_steps`` buffer bound, so shapes are uniform): trace size, jaxpr
-    size, and compile time are O(1) in len(ts).
+    size and compile time are O(1) in len(ts).  Adaptive segments carry
+    ``(x, h_final)`` across the boundaries: each segment seeds its step
+    from the previous segment's unclamped ``h_final``, so landing exactly
+    on an observation time costs one clamped step, not a collapsed restart
+    (``threaded=False`` restarts every segment from ``cfg.initial_step``).
+    A failed segment is poisoned per ``cfg.on_failure`` — per lane under
+    ``lanes`` — and the poison propagates to the later observations.
+    Observation times are shared across lanes.
 
     Returns (obs, sols): ``obs`` the stacked observations (leading dim
-    len(ts)), ``sols`` an AdaptiveSolution whose every field carries a
-    leading len(ts) segment axis.
+    len(ts)), ``sols`` the stepper's solution tuple with a leading len(ts)
+    segment axis on every field.
     """
     dtype = jnp.result_type(float)
     ts = jnp.asarray(ts, dtype)
     t_starts = segment_starts(t0, ts)
+    if not isinstance(stepping, AdaptiveConfig):
+        def body(x, seg):
+            sol = rk_solve_fixed(f, tab, x, *seg, stepping, params,
+                                 combine_backend)
+            return sol.x_final, sol
+
+        _, sols = jax.lax.scan(body, x0, (t_starts, ts))
+        return sols.x_final, sols
 
     def body(carry, seg):
         x, h = carry
-        a, b = seg
-        sol = rk_solve_adaptive(f, tab, x, a, b, params, cfg,
-                                combine_backend, h0=h)
-        x = apply_on_failure(sol.x_final, sol.succeeded, cfg.on_failure)
+        sol = rk_solve_segment(f, tab, x, *seg, params, stepping,
+                               combine_backend, lanes=lanes,
+                               h0=h if threaded else None)
+        x = apply_on_failure(sol.x_final, sol.succeeded, stepping.on_failure)
         sol = sol._replace(x_final=x)
         return (x, sol.h_final), sol
 
     # h0 = 0 makes the first segment fall back to cfg.initial_step.
-    _, sols = jax.lax.scan(body, (x0, jnp.zeros((), dtype)),
-                           (t_starts, ts))
+    h0 = jnp.zeros((lane_count(x0),) if lanes else (), dtype)
+    _, sols = jax.lax.scan(body, (x0, h0), (t_starts, ts))
     return sols.x_final, sols
-
-
-def rk_solve_adaptive_saveat(f: VectorField, tab: ButcherTableau, x0, t0,
-                             ts: jnp.ndarray, params, cfg: AdaptiveConfig,
-                             combine_backend: str = "auto"):
-    """List-of-segments convenience wrapper around the scanned driver.
-
-    Returns (obs, sols) with ``sols`` a Python list of per-segment
-    AdaptiveSolutions (unstacked views into the scanned buffers).  Solver
-    hot paths use ``rk_solve_adaptive_saveat_stacked`` directly — the
-    unstacking here costs O(len(ts)) trace equations and is meant for
-    inspection and tests.
-    """
-    obs, stacked = rk_solve_adaptive_saveat_stacked(
-        f, tab, x0, t0, ts, params, cfg, combine_backend)
-    sols = [jax.tree_util.tree_map(lambda l: l[i], stacked)
-            for i in range(ts.shape[0])]
-    return obs, sols
 
 
 def hermite_observe(f: VectorField, tab: ButcherTableau,

@@ -47,6 +47,15 @@ with respect to the whole ``params`` and a whole-tree sum per step.  The
 lane-batched step (``symplectic_step_adjoint_lanes``, whose lanes may read
 different slices) and the continuous adjoint (core/adjoint.py) always take
 the dense path.
+
+One driver serves every cell (``symplectic_solve``): its static arguments
+are the field, the tableau, the stepping (an ``int`` fixed grid or an
+``AdaptiveConfig``), the combine backend, whether the state is a batch of
+lanes, and whether the solve is observed at the times ``ts`` (segmented)
+or at ``t1`` alone.  Its forward pass is the stepper solve (core/rk.py:
+``rk_solve_segment`` for ``t1``, ``rk_solve_segments`` for ``ts``) and
+keeps Algorithm 1's checkpoints as the residuals; its backward pass is
+``symplectic_replay``, a function of those checkpoints alone.
 """
 from __future__ import annotations
 
@@ -59,12 +68,8 @@ import jax.numpy as jnp
 from ..runtime import scopes
 from .combine import StageCombiner, alloc_stages, get_combiner, set_stage
 from .rk import (AdaptiveConfig, SlicedField, VectorField, apply_on_failure,
-                 apply_on_failure_lanes, lane_bcast, rk_solve_adaptive,
-                 rk_solve_adaptive_batched,
-                 rk_solve_adaptive_batched_saveat_stacked,
-                 rk_solve_adaptive_saveat_stacked, rk_solve_fixed, rk_stages,
-                 segment_starts, take_slice, time_lift as _lift,
-                 time_unlift as _unlift,
+                 lane_bcast, rk_solve_segment, rk_solve_segments, rk_stages,
+                 take_slice, time_lift as _lift, time_unlift as _unlift,
                  time_zero_cotangent as _time_zero)
 from .tableau import ButcherTableau
 
@@ -173,297 +178,18 @@ def symplectic_step_adjoint(f: VectorField, tab: ButcherTableau,
 
 
 # ---------------------------------------------------------------------------
-# Fixed-grid driver
-# ---------------------------------------------------------------------------
-
-# All custom_vjp drivers below take their scalar times as (1,)-shaped
-# arrays (see rk.time_lift); the public odeint_* wrappers keep the scalar
-# signature and lift at the boundary.
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _odeint_symplectic_r1(f: VectorField, tab: ButcherTableau, n_steps: int,
-                          combine_backend: str, x0, t0r, t1r, params):
-    sol = rk_solve_fixed(f, tab, x0, _unlift(t0r), _unlift(t1r), n_steps,
-                         params,
-                         combine_backend)
-    return sol.x_final
-
-
-def odeint_symplectic(f: VectorField, tab: ButcherTableau, n_steps: int,
-                      combine_backend: str, x0, t0, t1, params):
-    return _odeint_symplectic_r1(f, tab, n_steps, combine_backend,
-                                 x0, _lift(t0), _lift(t1), params)
-
-
-def _sym_fwd(f, tab, n_steps, combine_backend, x0, t0r, t1r, params):
-    sol = rk_solve_fixed(f, tab, x0, _unlift(t0r), _unlift(t1r), n_steps,
-                         params,
-                         combine_backend)
-    # Residuals = Algorithm 1's checkpoints (plus the primal times, kept
-    # only so the backward pass can emit dtype-matched zero cotangents).
-    return sol.x_final, (sol.xs, sol.ts, sol.h, params, t0r, t1r)
-
-
-def _sym_bwd(f, tab, n_steps, combine_backend, res, lam_N):
-    xs, ts, h, params, t0, t1 = res
-    combiner = get_combiner(tab, combine_backend)
-
-    def body(carry, inputs):
-        lam, gtheta = carry
-        x_n, t_n = inputs
-        return symplectic_step_adjoint(f, tab, x_n, t_n, h, params, lam,
-                                       gtheta, combiner), None
-
-    (lam0, gtheta), _ = jax.lax.scan(body, (lam_N, _tree_zeros(params)),
-                                     (xs, ts), reverse=True)
-    return (lam0, _time_zero(t0), _time_zero(t1), gtheta)
-
-
-_odeint_symplectic_r1.defvjp(_sym_fwd, _sym_bwd)
-
-
-# ---------------------------------------------------------------------------
-# Adaptive driver (bounded checkpoint buffer, masked reverse scan)
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _odeint_symplectic_adaptive_r1(f: VectorField, tab: ButcherTableau,
-                                   cfg: AdaptiveConfig, combine_backend: str,
-                                   x0, t0r, t1r, params):
-    sol = rk_solve_adaptive(f, tab, x0, _unlift(t0r), _unlift(t1r), params,
-                            cfg,
-                            combine_backend)
-    return apply_on_failure(sol.x_final, sol.succeeded, cfg.on_failure)
-
-
-def odeint_symplectic_adaptive(f: VectorField, tab: ButcherTableau,
-                               cfg: AdaptiveConfig, combine_backend: str,
-                               x0, t0, t1, params):
-    return _odeint_symplectic_adaptive_r1(f, tab, cfg, combine_backend,
-                                          x0, _lift(t0), _lift(t1), params)
-
-
-def _syma_fwd(f, tab, cfg, combine_backend, x0, t0r, t1r, params):
-    sol = rk_solve_adaptive(f, tab, x0, _unlift(t0r), _unlift(t1r), params,
-                            cfg,
-                            combine_backend)
-    res = (sol.xs, sol.ts, sol.hs, sol.n_accepted, params, t0r, t1r)
-    x_final = apply_on_failure(sol.x_final, sol.succeeded, cfg.on_failure)
-    return x_final, res
-
-
-def _syma_bwd(f, tab, cfg, combine_backend, res, lam_N):
-    xs, ts, hs, n_acc, params, t0, t1 = res
-    combiner = get_combiner(tab, combine_backend)
-
-    def body(carry, inputs):
-        lam, gtheta = carry
-        x_n, t_n, h_n, idx = inputs
-        valid = idx < n_acc
-
-        def live(_):
-            return symplectic_step_adjoint(
-                f, tab, x_n, t_n, h_n, params, lam, gtheta, combiner)
-
-        def dead(_):
-            return lam, gtheta
-
-        lam, gtheta = jax.lax.cond(valid, live, dead, None)
-        return (lam, gtheta), None
-
-    idxs = jnp.arange(cfg.max_steps)
-    (lam0, gtheta), _ = jax.lax.scan(
-        body, (lam_N, _tree_zeros(params)), (xs, ts, hs, idxs),
-        reverse=True)
-    return (lam0, _time_zero(t0), _time_zero(t1), gtheta)
-
-
-_odeint_symplectic_adaptive_r1.defvjp(_syma_fwd, _syma_bwd)
-
-
-# ---------------------------------------------------------------------------
-# SaveAt drivers: observation at user times ts, exact gradient preserved.
+# Lane-batched step: per-lane accepted grids, exact per lane.
 #
-# The solve is split into checkpointed segments at the observation times
-# (each observation is a segment endpoint, so no interpolation enters the
-# differentiated map).  The backward pass walks the segments in reverse;
-# each segment is the existing Algorithm 2 scan, and the incoming cotangent
-# of observation i is injected into lambda at its segment boundary before
-# that segment's scan runs.  Theorem 2 then applies per segment, so the
-# full gradient of any loss over the observations is exact to rounding.
-#
-# Both directions are lax.scans OVER THE SEGMENTS (segments share n_steps /
-# max_steps, so shapes are uniform): the forward stacks per-segment
-# checkpoint buffers as scan outputs, the backward is a reverse scan whose
-# body injects the i-th observation cotangent (an indexed read from the
-# stacked obs_bar via the scan's own slicing) and then runs the per-segment
-# Algorithm 2 scan.  Trace size, jaxpr size, and compile time are O(1) in
-# the number of observations — see docs/adaptive.md.
-# ---------------------------------------------------------------------------
-
-def _sym_saveat_solve(f, tab, n_steps, combine_backend, x0, t0r, ts, params):
-    """Forward segmented fixed-grid solve; returns (obs, residuals)."""
-
-    def body(x, seg):
-        a, b = seg
-        sol = rk_solve_fixed(f, tab, x, a, b, n_steps, params,
-                             combine_backend)
-        return sol.x_final, (sol.x_final, sol.xs, sol.ts, sol.h)
-
-    _, (obs, seg_xs, seg_ts, seg_hs) = jax.lax.scan(
-        body, x0, (segment_starts(_unlift(t0r), ts), ts))
-    return obs, (seg_xs, seg_ts, seg_hs, params, t0r, ts)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _odeint_symplectic_saveat_r1(f: VectorField, tab: ButcherTableau,
-                                 n_steps: int, combine_backend: str,
-                                 x0, t0r, ts, params):
-    obs, _ = _sym_saveat_solve(f, tab, n_steps, combine_backend,
-                               x0, t0r, ts, params)
-    return obs
-
-
-def odeint_symplectic_saveat(f: VectorField, tab: ButcherTableau,
-                             n_steps: int, combine_backend: str,
-                             x0, t0, ts, params):
-    """Fixed-grid solve observed at ts (n_steps per segment).
-
-    Returns the solution stacked over the observation times (leading dim
-    len(ts) per leaf).
-    """
-    return _odeint_symplectic_saveat_r1(f, tab, n_steps, combine_backend,
-                                        x0, _lift(t0), ts, params)
-
-
-def _sym_saveat_fwd(f, tab, n_steps, combine_backend, x0, t0r, ts, params):
-    return _sym_saveat_solve(f, tab, n_steps, combine_backend,
-                             x0, t0r, ts, params)
-
-
-def _sym_saveat_bwd(f, tab, n_steps, combine_backend, res, obs_bar):
-    xs_all, ts_all, hs_all, params, t0, ts = res
-    combiner = get_combiner(tab, combine_backend)
-    lam0 = jax.tree_util.tree_map(lambda l: jnp.zeros_like(l[0]), obs_bar)
-
-    def seg_body(carry, seg):
-        lam, gtheta = carry
-        ob_i, seg_xs, seg_ts, h_seg = seg
-        # inject the cotangent arriving at this segment boundary
-        lam = _tree_add(lam, ob_i)
-
-        def body(carry_c, inputs):
-            lam_c, g_c = carry_c
-            x_n, t_n = inputs
-            return symplectic_step_adjoint(
-                f, tab, x_n, t_n, h_seg, params, lam_c, g_c, combiner), None
-
-        (lam, gtheta), _ = jax.lax.scan(body, (lam, gtheta),
-                                        (seg_xs, seg_ts), reverse=True)
-        return (lam, gtheta), None
-
-    (lam, gtheta), _ = jax.lax.scan(
-        seg_body, (lam0, _tree_zeros(params)),
-        (obs_bar, xs_all, ts_all, hs_all), reverse=True)
-    return (lam, _time_zero(t0), _time_zero(ts), gtheta)
-
-
-_odeint_symplectic_saveat_r1.defvjp(_sym_saveat_fwd, _sym_saveat_bwd)
-
-
-def _syma_saveat_solve(f, tab, cfg, combine_backend, x0, t0r, ts, params):
-    obs, sols = rk_solve_adaptive_saveat_stacked(
-        f, tab, x0, _unlift(t0r), ts, params, cfg, combine_backend)
-    res = (sols.xs, sols.ts, sols.hs, sols.n_accepted, params, t0r, ts)
-    return obs, res
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _odeint_symplectic_saveat_adaptive_r1(f: VectorField,
-                                          tab: ButcherTableau,
-                                          cfg: AdaptiveConfig,
-                                          combine_backend: str,
-                                          x0, t0r, ts, params):
-    obs, _ = _syma_saveat_solve(f, tab, cfg, combine_backend,
-                                x0, t0r, ts, params)
-    return obs
-
-
-def odeint_symplectic_saveat_adaptive(f: VectorField, tab: ButcherTableau,
-                                      cfg: AdaptiveConfig,
-                                      combine_backend: str,
-                                      x0, t0, ts, params):
-    """Adaptive solve observed at ts (one adaptive segment per interval).
-
-    The controller threads its unclamped step across segment boundaries
-    (rk_solve_adaptive_saveat), so observation times cost one clamped
-    landing step each instead of a collapsed restart.  Failed segments
-    follow cfg.on_failure.
-    """
-    return _odeint_symplectic_saveat_adaptive_r1(
-        f, tab, cfg, combine_backend, x0, _lift(t0), ts, params)
-
-
-def _syma_saveat_fwd(f, tab, cfg, combine_backend, x0, t0r, ts, params):
-    return _syma_saveat_solve(f, tab, cfg, combine_backend,
-                              x0, t0r, ts, params)
-
-
-def _syma_saveat_bwd(f, tab, cfg, combine_backend, res, obs_bar):
-    xs_all, ts_all, hs_all, n_accs, params, t0, ts = res
-    combiner = get_combiner(tab, combine_backend)
-    lam0 = jax.tree_util.tree_map(lambda l: jnp.zeros_like(l[0]), obs_bar)
-    idxs = jnp.arange(cfg.max_steps)
-
-    def seg_body(carry, seg):
-        lam, gtheta = carry
-        ob_i, seg_xs, seg_ts, seg_hs, n_acc = seg
-        lam = _tree_add(lam, ob_i)
-
-        def body(carry_c, inputs):
-            lam_c, g_c = carry_c
-            x_n, t_n, h_n, idx = inputs
-            valid = idx < n_acc
-
-            def live(_):
-                return symplectic_step_adjoint(
-                    f, tab, x_n, t_n, h_n, params, lam_c, g_c, combiner)
-
-            def dead(_):
-                return lam_c, g_c
-
-            out = jax.lax.cond(valid, live, dead, None)
-            return out, None
-
-        (lam, gtheta), _ = jax.lax.scan(
-            body, (lam, gtheta), (seg_xs, seg_ts, seg_hs, idxs),
-            reverse=True)
-        return (lam, gtheta), None
-
-    (lam, gtheta), _ = jax.lax.scan(
-        seg_body, (lam0, _tree_zeros(params)),
-        (obs_bar, xs_all, ts_all, hs_all, n_accs), reverse=True)
-    return (lam, _time_zero(t0), _time_zero(ts), gtheta)
-
-
-_odeint_symplectic_saveat_adaptive_r1.defvjp(_syma_saveat_fwd,
-                                             _syma_saveat_bwd)
-
-
-# ---------------------------------------------------------------------------
-# Batch-native adaptive drivers: per-lane accepted grids, exact per lane.
-#
-# The forward pass is the masked batch-native driver
-# (rk_solve_adaptive_batched): each lane realizes ITS OWN accepted step
-# sequence.  That sequence is the gradient-defining object of the symplectic
-# adjoint, so the backward pass must replay each lane's own grid — the
-# reverse scan walks the shared (max_steps, B) checkpoint rows, runs one
-# lane-vmapped Algorithm-2 step per row, and masks each lane by its own
-# n_accepted: a lane with fewer accepted steps simply carries its lambda
-# unchanged through the rows beyond its count.  Theorem 2 then applies per
-# lane, so the batched gradient equals the sum of per-lane single-solve
-# gradients to rounding (tests/test_batch.py pins it against a Python loop
-# of single solves).
+# A batch-native adaptive forward pass (``AdaptiveStepper`` on a lane state)
+# realizes each lane's OWN accepted step sequence.  That sequence is the
+# gradient-defining object of the symplectic adjoint, so the backward pass
+# replays each lane's own grid: the reverse scan walks the shared
+# (max_steps, B) checkpoint rows, runs one lane-vmapped Algorithm-2 step per
+# row, and masks each lane by its own n_accepted — a lane with fewer
+# accepted steps carries its lambda unchanged through the rows beyond its
+# count.  Theorem 2 then applies per lane, so the batched gradient equals
+# the sum of per-lane single-solve gradients to rounding
+# (tests/test_batch.py pins it against a Python loop of single solves).
 # ---------------------------------------------------------------------------
 
 def symplectic_step_adjoint_lanes(f: VectorField, tab: ButcherTableau,
@@ -530,144 +256,176 @@ def symplectic_step_adjoint_lanes(f: VectorField, tab: ButcherTableau,
     return lam_n, gtheta
 
 
-def _masked_lanes_alg2_scan(f, tab, combiner, params, max_steps,
-                            xs, ts, hs, n_acc, lam, gtheta):
-    """Reverse Algorithm-2 scan over (max_steps, B) checkpoint rows.
+# ---------------------------------------------------------------------------
+# The backward pass: Algorithm 2 from the checkpoints alone.
+# ---------------------------------------------------------------------------
 
-    ``n_acc`` is (B,); rows >= a lane's count leave that lane's lambda and
-    its grad-theta contribution untouched.  Rows beyond EVERY lane's count
-    skip the stage recomputation entirely (lax.cond on any(valid)).
+def _replay_segment(f, tab, combiner, params, ckpt, lam, gtheta):
+    """One reverse Algorithm-2 scan over one segment's checkpoints.
+
+    ``(xs, ts, h)`` is a fixed grid: every row is a step (a lane batch on
+    a fixed grid is one state, never masked).  ``(xs, ts, hs,
+    n_accepted)`` is an adaptive grid: rows at or past ``n_accepted`` are
+    scratch and leave the carry untouched; a (B,) ``n_accepted`` is a lane
+    batch, each lane masked by its own count, and rows past every lane's
+    count skip the stage recomputation (``lax.cond`` on ``any(valid)``).
     """
-    def body(carry, inputs):
-        lam, gtheta = carry
-        x_n, t_n, h_n, idx = inputs
-        valid = idx < n_acc
+    if len(ckpt) == 3:
+        xs, ts, h = ckpt
 
-        def live(args):
-            lam, gtheta = args
-            lam2, gstep = symplectic_step_adjoint_lanes(
-                f, tab, x_n, t_n, h_n, params, lam, combiner)
-            lam = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(lane_bcast(valid, a), b, a),
-                lam, lam2)
-            with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
-                gsum = jax.tree_util.tree_map(
-                    lambda g: jnp.sum(jnp.where(lane_bcast(valid, g), g,
-                                                jnp.zeros((), g.dtype)),
-                                      axis=0), gstep)
-            return lam, _accumulate(gtheta, gsum)
+        def body(carry, inputs):
+            lam, gtheta = carry
+            x_n, t_n = inputs
+            return symplectic_step_adjoint(f, tab, x_n, t_n, h, params, lam,
+                                           gtheta, combiner), None
 
-        def dead(args):
-            return args
+        rows = (xs, ts)
+    elif jnp.ndim(ckpt[3]) == 0:
+        xs, ts, hs, n_acc = ckpt
 
-        out = jax.lax.cond(jnp.any(valid), live, dead, (lam, gtheta))
-        return out, None
+        def body(carry, inputs):
+            lam, gtheta = carry
+            x_n, t_n, h_n, idx = inputs
+            valid = idx < n_acc
 
-    idxs = jnp.arange(max_steps)
-    (lam, gtheta), _ = jax.lax.scan(body, (lam, gtheta),
-                                    (xs, ts, hs, idxs), reverse=True)
+            def live(_):
+                return symplectic_step_adjoint(
+                    f, tab, x_n, t_n, h_n, params, lam, gtheta, combiner)
+
+            def dead(_):
+                return lam, gtheta
+
+            return jax.lax.cond(valid, live, dead, None), None
+
+        rows = (xs, ts, hs, jnp.arange(ts.shape[0]))
+    else:
+        xs, ts, hs, n_acc = ckpt
+
+        def body(carry, inputs):
+            x_n, t_n, h_n, idx = inputs
+            valid = idx < n_acc
+
+            def live(args):
+                lam, gtheta = args
+                lam2, gstep = symplectic_step_adjoint_lanes(
+                    f, tab, x_n, t_n, h_n, params, lam, combiner)
+                lam = jax.tree_util.tree_map(
+                    lambda a, b: jnp.where(lane_bcast(valid, a), b, a),
+                    lam, lam2)
+                with jax.named_scope(scopes.ADJOINT_ACCUMULATE):
+                    gsum = jax.tree_util.tree_map(
+                        lambda g: jnp.sum(jnp.where(
+                            lane_bcast(valid, g), g,
+                            jnp.zeros((), g.dtype)), axis=0), gstep)
+                return lam, _accumulate(gtheta, gsum)
+
+            def dead(args):
+                return args
+
+            return jax.lax.cond(jnp.any(valid), live, dead, carry), None
+
+        rows = (xs, ts, hs, jnp.arange(ts.shape[0]))
+    (lam, gtheta), _ = jax.lax.scan(body, (lam, gtheta), rows, reverse=True)
     return lam, gtheta
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _odeint_symplectic_adaptive_batched_r1(f: VectorField,
-                                           tab: ButcherTableau,
-                                           cfg: AdaptiveConfig,
-                                           combine_backend: str,
-                                           x0, t0r, t1r, params):
-    sol = rk_solve_adaptive_batched(f, tab, x0, _unlift(t0r), _unlift(t1r),
-                                    params, cfg,
-                                    combine_backend)
-    return apply_on_failure_lanes(sol.x_final, sol.succeeded, cfg.on_failure)
+def symplectic_replay(f: VectorField, tab: ButcherTableau, ckpt, params,
+                      obs_bar, *, segmented: bool = False,
+                      combine_backend: str = "auto"):
+    """Algorithm 2: the exact gradient from Algorithm 1's checkpoints.
 
-
-def odeint_symplectic_adaptive_batched(f: VectorField, tab: ButcherTableau,
-                                       cfg: AdaptiveConfig,
-                                       combine_backend: str,
-                                       x0, t0, t1, params):
-    """Batch-native adaptive solve (lane axis 0) with the exact symplectic
-    adjoint replaying each lane's own accepted grid."""
-    return _odeint_symplectic_adaptive_batched_r1(
-        f, tab, cfg, combine_backend, x0, _lift(t0), _lift(t1), params)
-
-
-def _symab_fwd(f, tab, cfg, combine_backend, x0, t0r, t1r, params):
-    sol = rk_solve_adaptive_batched(f, tab, x0, _unlift(t0r), _unlift(t1r),
-                                    params, cfg,
-                                    combine_backend)
-    res = (sol.xs, sol.ts, sol.hs, sol.n_accepted, params, t0r, t1r)
-    x_final = apply_on_failure_lanes(sol.x_final, sol.succeeded,
-                                     cfg.on_failure)
-    return x_final, res
-
-
-def _symab_bwd(f, tab, cfg, combine_backend, res, lam_N):
-    xs, ts, hs, n_acc, params, t0, t1 = res
-    combiner = get_combiner(tab, combine_backend)
-    lam0, gtheta = _masked_lanes_alg2_scan(
-        f, tab, combiner, params, cfg.max_steps, xs, ts, hs, n_acc,
-        lam_N, _tree_zeros(params))
-    return (lam0, _time_zero(t0), _time_zero(t1), gtheta)
-
-
-_odeint_symplectic_adaptive_batched_r1.defvjp(_symab_fwd, _symab_bwd)
-
-
-def _symab_saveat_solve(f, tab, cfg, combine_backend, x0, t0r, ts, params):
-    obs, sols = rk_solve_adaptive_batched_saveat_stacked(
-        f, tab, x0, _unlift(t0r), ts, params, cfg, combine_backend)
-    res = (sols.xs, sols.ts, sols.hs, sols.n_accepted, params, t0r, ts)
-    return obs, res
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _odeint_symplectic_saveat_adaptive_batched_r1(
-        f: VectorField, tab: ButcherTableau, cfg: AdaptiveConfig,
-        combine_backend: str, x0, t0r, ts, params):
-    obs, _ = _symab_saveat_solve(f, tab, cfg, combine_backend,
-                                 x0, t0r, ts, params)
-    return obs
-
-
-def odeint_symplectic_saveat_adaptive_batched(
-        f: VectorField, tab: ButcherTableau, cfg: AdaptiveConfig,
-        combine_backend: str, x0, t0, ts, params):
-    """Batch-native adaptive solve observed at the (shared) times ``ts``.
-
-    Per-lane controller state threads across observation boundaries
-    (rk_solve_adaptive_batched_saveat_stacked); the backward pass walks the
-    segments in reverse, injects the per-lane observation cotangent at each
-    boundary, and replays every lane's own accepted grid inside the
-    segment.  Exact per lane to rounding.
+    ``ckpt`` is ``(xs, ts, h)`` of a fixed grid or ``(xs, ts, hs,
+    n_accepted)`` of an adaptive one (lane-batched when ``n_accepted`` is
+    (B,)), as a stepper's solution holds them.  ``obs_bar`` is the
+    cotangent of the observation: of the final state, or with
+    ``segmented`` of the stacked observations at ``ts``, in which case
+    every ``ckpt`` leaf carries a leading segment axis.  The segments are
+    walked in reverse: observation i's cotangent is added into lambda at
+    its segment boundary, then that segment's reverse scan runs; Theorem 2
+    holds per segment, so the gradient of any loss over the observations is
+    exact to rounding.  Returns ``(lambda_0, grad_theta)``.
     """
-    return _odeint_symplectic_saveat_adaptive_batched_r1(
-        f, tab, cfg, combine_backend, x0, _lift(t0), ts, params)
-
-
-def _symab_saveat_fwd(f, tab, cfg, combine_backend, x0, t0r, ts, params):
-    return _symab_saveat_solve(f, tab, cfg, combine_backend,
-                               x0, t0r, ts, params)
-
-
-def _symab_saveat_bwd(f, tab, cfg, combine_backend, res, obs_bar):
-    xs_all, ts_all, hs_all, n_accs, params, t0, ts = res
     combiner = get_combiner(tab, combine_backend)
-    lam0 = jax.tree_util.tree_map(lambda l: jnp.zeros_like(l[0]), obs_bar)
+    if not segmented:
+        return _replay_segment(f, tab, combiner, params, ckpt, obs_bar,
+                               _tree_zeros(params))
 
     def seg_body(carry, seg):
         lam, gtheta = carry
-        ob_i, seg_xs, seg_ts, seg_hs, n_acc = seg
+        ob_i, seg_ckpt = seg
         lam = _tree_add(lam, ob_i)
-        lam, gtheta = _masked_lanes_alg2_scan(
-            f, tab, combiner, params, cfg.max_steps,
-            seg_xs, seg_ts, seg_hs, n_acc, lam, gtheta)
-        return (lam, gtheta), None
+        return _replay_segment(f, tab, combiner, params, seg_ckpt, lam,
+                               gtheta), None
 
-    (lam, gtheta), _ = jax.lax.scan(
-        seg_body, (lam0, _tree_zeros(params)),
-        (obs_bar, xs_all, ts_all, hs_all, n_accs), reverse=True)
-    return (lam, _time_zero(t0), _time_zero(ts), gtheta)
+    lam0 = jax.tree_util.tree_map(lambda l: jnp.zeros_like(l[0]), obs_bar)
+    (lam, gtheta), _ = jax.lax.scan(seg_body, (lam0, _tree_zeros(params)),
+                                    (obs_bar, ckpt), reverse=True)
+    return lam, gtheta
 
 
-_odeint_symplectic_saveat_adaptive_batched_r1.defvjp(_symab_saveat_fwd,
-                                                     _symab_saveat_bwd)
+# ---------------------------------------------------------------------------
+# The driver: one custom_vjp for every (stepping, observation, lanes) cell.
+#
+# Its scalar times cross the custom_vjp boundary as (1,)-shaped arrays (see
+# rk.time_lift); ``symplectic_solve`` lifts them.  ``t1`` runs unscanned: the
+# single segment's solve and replay are called directly, with no segment
+# axis.  ``ts`` runs the segments inside one lax.scan each way, so trace
+# size, jaxpr size and compile time are O(1) in len(ts) (docs/adaptive.md).
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
+def _symplectic_r1(f, tab, stepping, combine_backend, lanes, segmented,
+                   x0, t0r, tobs, params):
+    return _symplectic_fwd(f, tab, stepping, combine_backend, lanes,
+                           segmented, x0, t0r, tobs, params)[0]
+
+
+def _symplectic_fwd(f, tab, stepping, combine_backend, lanes, segmented,
+                    x0, t0r, tobs, params):
+    adaptive = isinstance(stepping, AdaptiveConfig)
+    if segmented:
+        obs, sol = rk_solve_segments(f, tab, x0, _unlift(t0r), tobs, params,
+                                     stepping, combine_backend, lanes=lanes)
+    else:
+        sol = rk_solve_segment(f, tab, x0, _unlift(t0r), _unlift(tobs),
+                               params, stepping, combine_backend,
+                               lanes=lanes)
+        obs = sol.x_final
+        if adaptive:
+            obs = apply_on_failure(obs, sol.succeeded, stepping.on_failure)
+    # Residuals = Algorithm 1's checkpoints (plus the primal times, kept
+    # only so the backward pass can emit dtype-matched zero cotangents).
+    ckpt = ((sol.xs, sol.ts, sol.hs, sol.n_accepted) if adaptive
+            else (sol.xs, sol.ts, sol.h))
+    return obs, (ckpt, params, t0r, tobs)
+
+
+def _symplectic_bwd(f, tab, stepping, combine_backend, lanes, segmented,
+                    res, obs_bar):
+    ckpt, params, t0r, tobs = res
+    lam0, gtheta = symplectic_replay(f, tab, ckpt, params, obs_bar,
+                                     segmented=segmented,
+                                     combine_backend=combine_backend)
+    return (lam0, _time_zero(t0r), _time_zero(tobs), gtheta)
+
+
+_symplectic_r1.defvjp(_symplectic_fwd, _symplectic_bwd)
+
+
+def symplectic_solve(f: VectorField, tab: ButcherTableau, stepping,
+                     combine_backend: str, x0, t0, tobs, params, *,
+                     lanes: bool = False, segmented: bool = False):
+    """Solve with the exact symplectic-adjoint gradient.
+
+    ``stepping`` is an ``int`` (N equal steps per segment) or an
+    ``AdaptiveConfig`` (``max_steps`` per segment; failures follow its
+    ``on_failure``).  ``tobs`` is the end time ``t1`` (the final state is
+    returned), or with ``segmented`` the observation times ``ts`` (the
+    states at ``ts`` are returned stacked, the adaptive controller step
+    threading across the boundaries).  ``lanes`` declares lane axis 0 of
+    every state leaf a batch of independent trajectories: an adaptive
+    solve then runs one controller per lane and the replay masks each lane
+    by its own grid; ``t1`` may then be (B,), one horizon per lane.
+    """
+    return _symplectic_r1(f, tab, stepping, combine_backend, lanes,
+                          segmented, x0, _lift(t0), _lift(tobs), params)

@@ -22,8 +22,11 @@ axes with ``shard_map``.  The shape of the subsystem:
 * ``check_vma=False`` throughout: the adaptive driver is a
   ``lax.while_loop`` and shard_map has no replication rule for it.
 
-The gradient path additionally requires every custom_vjp driver to expose
-rank-1 time inputs — see ``repro.core.rk.time_lift``.
+``core/api.py::_solve_sharded`` wraps the SAME solve body the unsharded
+batched solve runs (the strategy's ``observe``) with the helpers below.
+The gradient path additionally requires the custom_vjp drivers (the one
+symplectic driver and the continuous-adjoint drivers) to expose rank-1
+time inputs — see ``repro.core.rk.time_lift``.
 """
 from __future__ import annotations
 

@@ -6,7 +6,11 @@ Covers the api_redesign acceptance criteria:
   * golden equivalence: the ``odeint`` / ``odeint_with_stats`` shims pin
     EXACTLY (values and stats dicts) to the pre-redesign behavior — i.e.
     to the unchanged underlying drivers and the historical stats formulas —
-    for all five gradient modes on fixed and adaptive grids;
+    for all five gradient modes on fixed and adaptive grids; the
+    symplectic value is the plain stepper solve bitwise, and its gradient
+    is DirectBackprop's to rounding;
+  * the one symplectic driver's gradient matches jax.grad through
+    DirectBackprop in every (stepping, observation, lanes) cell;
   * a new gradient strategy registers and solves WITHOUT editing solve();
   * the declarative capability matrix rejects every illegal combination
     with a uniform error;
@@ -28,10 +32,9 @@ from repro.core import (AdaptiveConfig, ContinuousAdjoint, DirectBackprop,
                         capability_matrix, get_tableau, hermite_observe,
                         odeint, odeint_adjoint, odeint_adjoint_adaptive,
                         odeint_backprop, odeint_remat_solve,
-                        odeint_remat_step, odeint_symplectic,
-                        odeint_symplectic_adaptive, odeint_symplectic_saveat,
-                        odeint_symplectic_saveat_adaptive, odeint_with_stats,
-                        register_gradient, rk_solve_adaptive, solve)
+                        odeint_remat_step, odeint_with_stats,
+                        register_gradient, rk_solve_adaptive, rk_solve_fixed,
+                        rk_solve_segments, rk_step, solve)
 from repro.core import api as api_mod
 
 
@@ -148,8 +151,9 @@ def test_solution_stats_adaptive_match_controller():
 # --- golden equivalence: shims == pre-redesign drivers -----------------------
 
 FIXED_DRIVERS = {
-    "symplectic": lambda n, x, t0, t1: odeint_symplectic(
-        mlp_field, TAB, n, "auto", x, t0, t1, PARAMS),
+    # the symplectic value is the plain stepper solve, bit for bit
+    "symplectic": lambda n, x, t0, t1: rk_solve_fixed(
+        mlp_field, TAB, x, t0, t1, n, PARAMS).x_final,
     "backprop": lambda n, x, t0, t1: odeint_backprop(
         mlp_field, TAB, n, x, t0, t1, PARAMS, "auto"),
     "remat_step": lambda n, x, t0, t1: odeint_remat_step(
@@ -159,6 +163,71 @@ FIXED_DRIVERS = {
     "adjoint": lambda n, x, t0, t1: odeint_adjoint(
         mlp_field, TAB, n, 1, "auto", x, t0, t1, PARAMS),
 }
+
+
+def lane_field(x, t, params):
+    """``mlp_field`` row-wise: a (B, 4) state is B states."""
+    h = jnp.tanh(x @ params["w1"].T + params["b1"] + t)
+    return h @ params["w2"].T + params["b2"]
+
+
+def _grid_replay(f, x0, params, saveat, stepping, lanes):
+    """DirectBackprop on the realized grid: a differentiable re-run of the
+    solve's own accepted steps (``rk_step`` along the recorded rows, rows
+    past ``n_accepted`` leaving the state unchanged), per lane on lanes.
+    An adaptive solve's while_loop admits no reverse mode, and its gradient
+    is that of the discrete map along the grid it realized."""
+    ts = TS3 if saveat.kind == "ts" else jnp.asarray([saveat.t1])
+    _, sols = rk_solve_segments(f, TAB, jax.lax.stop_gradient(x0),
+                                0.0, ts, jax.lax.stop_gradient(params),
+                                stepping, lanes=lanes)
+
+    def segment(x, ts_k, hs_k, n_k):
+        def body(x, row):
+            t, h, live = row
+            x_next, _ = rk_step(f, TAB, x, t, h, params)
+            return jnp.where(live, x_next, x), None
+
+        live = jnp.arange(ts_k.shape[0]) < n_k
+        return jax.lax.scan(body, x, (ts_k, hs_k, live))[0]
+
+    def trajectory(x, ts_l, hs_l, n_l):
+        ys = []
+        for k in range(ts.shape[0]):
+            x = segment(x, ts_l[k], hs_l[k], n_l[k])
+            ys.append(x)
+        return jnp.stack(ys)
+
+    if lanes:
+        ys = jax.vmap(trajectory, in_axes=(0, 2, 2, 1), out_axes=1)(
+            x0, sols.ts, sols.hs, sols.n_accepted)
+    else:
+        ys = trajectory(x0, sols.ts, sols.hs, sols.n_accepted)
+    return ys[-1] if saveat.kind == "t1" else ys
+
+
+def assert_symplectic_grad_exact(saveat, stepping, x0=X0, batch_axis=None,
+                                 f=mlp_field):
+    """jax.grad through the one symplectic driver == jax.grad through
+    DirectBackprop (on an adaptive solve's realized grid), to rounding."""
+    def loss(x0, params, gradient):
+        sol = solve(f, x0, params, saveat=saveat, gradient=gradient,
+                    stepping=stepping, batch_axis=batch_axis)
+        return jnp.sum(jnp.sin(sol.ys) ** 2)
+
+    g_sym = jax.grad(loss, argnums=(0, 1))(x0, PARAMS, SymplecticAdjoint())
+    if isinstance(stepping, AdaptiveConfig):
+        g_ref = jax.grad(
+            lambda x, p: jnp.sum(jnp.sin(_grid_replay(
+                f, x, p, saveat, stepping, batch_axis is not None)) ** 2),
+            argnums=(0, 1))(x0, PARAMS)
+    else:
+        g_ref = jax.grad(loss, argnums=(0, 1))(x0, PARAMS, DirectBackprop())
+    for a, b in zip(jax.tree_util.tree_leaves(g_sym),
+                    jax.tree_util.tree_leaves(g_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-9, atol=1e-11)
+    return g_sym
 
 
 @pytest.mark.parametrize("mode", list(GRAD_MODES))
@@ -173,6 +242,8 @@ def test_golden_fixed_t1(mode):
                 gradient=mode, stepping=6)
     assert_trees_equal(y, sol.ys)
     assert_trees_equal(sol.ys, sol.final_state)
+    if mode == "symplectic":
+        assert_symplectic_grad_exact(SaveAt(t1=1.0), 6)
 
 
 @pytest.mark.parametrize("mode", list(GRAD_MODES))
@@ -180,9 +251,10 @@ def test_golden_fixed_ts_segmented(mode):
     ys = shim_odeint(mlp_field, X0, PARAMS, ts=TS3, method="dopri5",
                      grad_mode=mode, n_steps=4)
     if mode == "symplectic":
-        ref = odeint_symplectic_saveat(mlp_field, TAB, 4, "auto", X0,
-                                       jnp.asarray(0.0), TS3, PARAMS)
+        ref, _ = rk_solve_segments(mlp_field, TAB, X0, jnp.asarray(0.0), TS3,
+                                   PARAMS, 4)
         assert_trees_equal(ys, ref)
+        assert_symplectic_grad_exact(SaveAt(ts=TS3), 4)
     else:
         # pre-redesign: chained per-segment driver solves
         x, t_prev = X0, jnp.asarray(0.0)
@@ -198,16 +270,15 @@ def test_golden_adaptive_t1(mode):
     y = shim_odeint(mlp_field, X0, PARAMS, t1=1.0, method="dopri5",
                     grad_mode=mode, adaptive=CFG)
     t0, t1 = jnp.asarray(0.0), jnp.asarray(1.0)
-    if mode == "symplectic":
-        ref = odeint_symplectic_adaptive(mlp_field, TAB, CFG, "auto",
-                                         X0, t0, t1, PARAMS)
-    elif mode == "adjoint":
+    if mode == "adjoint":
         ref = odeint_adjoint_adaptive(mlp_field, TAB, CFG, CFG, "auto",
                                       X0, t0, t1, PARAMS)
     else:
         sol = rk_solve_adaptive(mlp_field, TAB, X0, t0, t1, PARAMS, CFG)
         ref = apply_on_failure(sol.x_final, sol.succeeded, CFG.on_failure)
     assert_trees_equal(y, ref)
+    if mode == "symplectic":
+        assert_symplectic_grad_exact(SaveAt(t1=1.0), CFG)
 
 
 @pytest.mark.parametrize("mode", ["symplectic", "backprop", "adjoint"])
@@ -216,9 +287,10 @@ def test_golden_adaptive_ts(mode):
                      grad_mode=mode, adaptive=CFG)
     assert ys.shape == (3, 4)
     if mode == "symplectic":
-        ref = odeint_symplectic_saveat_adaptive(
-            mlp_field, TAB, CFG, "auto", X0, jnp.asarray(0.0), TS3, PARAMS)
+        ref, _ = rk_solve_segments(mlp_field, TAB, X0, jnp.asarray(0.0), TS3,
+                                   PARAMS, CFG)
         assert_trees_equal(ys, ref)
+        assert_symplectic_grad_exact(SaveAt(ts=TS3), CFG)
     elif mode == "adjoint":
         # pre-redesign: per-segment odeint_adjoint_adaptive (controller
         # RESTARTS at each observation boundary)
@@ -237,15 +309,44 @@ def test_golden_symplectic_gradient_through_shim():
                    grad_mode="symplectic", n_steps=6)
         return jnp.sum(jnp.tanh(y) ** 2)
 
-    def loss_driver(x0, params):
-        y = odeint_symplectic(mlp_field, TAB, 6, "auto", x0,
-                              jnp.asarray(0.0), jnp.asarray(1.0), params)
+    def loss_solve(x0, params, gradient):
+        y = solve(mlp_field, x0, params, saveat=SaveAt(t1=1.0),
+                  gradient=gradient, stepping=6).ys
         return jnp.sum(jnp.tanh(y) ** 2)
 
     with pytest.warns(DeprecationWarning, match="odeint-style"):
         g_shim = jax.grad(loss_shim, argnums=(0, 1))(X0, PARAMS)
-    g_drv = jax.grad(loss_driver, argnums=(0, 1))(X0, PARAMS)
-    assert_trees_equal(g_shim, g_drv)
+    g_sym = jax.grad(loss_solve, argnums=(0, 1))(X0, PARAMS,
+                                                 SymplecticAdjoint())
+    assert_trees_equal(g_shim, g_sym)
+    g_ref = jax.grad(loss_solve, argnums=(0, 1))(X0, PARAMS,
+                                                 DirectBackprop())
+    for a, b in zip(jax.tree_util.tree_leaves(g_sym),
+                    jax.tree_util.tree_leaves(g_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-10, atol=1e-12)
+
+
+# --- the one symplectic driver, every cell -----------------------------------
+
+X0_LANES = jnp.stack([X0, 2.0 * X0, -0.5 * X0])
+
+
+@pytest.mark.parametrize("lanes", [False, True], ids=["single", "lanes"])
+@pytest.mark.parametrize("saveat", [SaveAt(t1=1.0), SaveAt(ts=TS3)],
+                         ids=["t1", "ts"])
+@pytest.mark.parametrize("stepping", [6, CFG], ids=["fixed", "adaptive"])
+def test_symplectic_driver_gradient_matches_backprop(stepping, saveat, lanes):
+    x0 = X0_LANES if lanes else X0
+    g = assert_symplectic_grad_exact(saveat, stepping, x0=x0,
+                                     batch_axis=0 if lanes else None,
+                                     f=lane_field)
+    if lanes and not isinstance(stepping, AdaptiveConfig):
+        # a fixed grid cannot depend on the state: the lanes are one state
+        # to the replay, never masked, so the gradient is the unbatched
+        # solve's bit for bit
+        assert_trees_equal(g, assert_symplectic_grad_exact(
+            saveat, stepping, x0=x0, f=lane_field))
 
 
 def test_golden_with_stats_fixed():

@@ -27,7 +27,7 @@ jax.config.update("jax_enable_x64", True)
 from repro.core import (AdaptiveConfig, GRAD_MODES, get_tableau, odeint,
                         odeint_with_stats)
 from repro.core.rk import (_time_resolution, rk_solve_adaptive,
-                           rk_solve_adaptive_saveat, rk_step)
+                           rk_solve_segments, rk_step)
 
 # Deliberately exercises the deprecated odeint shims (shim regression suite).
 pytestmark = pytest.mark.filterwarnings(
@@ -144,10 +144,11 @@ def test_saveat_symplectic_gradient_exact_adaptive(method):
                          initial_step=0.1)
     w = jnp.arange(1.0, 4.0)
 
-    obs, sols = rk_solve_adaptive_saveat(mlp_field, tab, x0, 0.0, TS3,
-                                         params, cfg)
+    obs, stacked = rk_solve_segments(mlp_field, tab, x0, 0.0, TS3, params,
+                                     cfg)
     segs = []
-    for s in sols:
+    for i in range(TS3.shape[0]):
+        s = jax.tree_util.tree_map(lambda l: l[i], stacked)
         assert bool(s.succeeded)
         n = int(s.n_accepted)
         assert 0 < n < cfg.max_steps
@@ -343,8 +344,10 @@ def test_controller_h_threads_across_segments():
     cfg = AdaptiveConfig(rtol=1e-6, atol=1e-8, max_steps=64,
                          initial_step=1e-4)
     ts = jnp.array([0.5, 1.0])
-    _, sols = rk_solve_adaptive_saveat(linear, tab, jnp.ones(2), 0.0, ts,
-                                       LIN_P, cfg)
+    _, stacked = rk_solve_segments(linear, tab, jnp.ones(2), 0.0, ts, LIN_P,
+                                   cfg)
+    sols = [jax.tree_util.tree_map(lambda l: l[i], stacked)
+            for i in range(ts.shape[0])]
     assert all(bool(s.succeeded) for s in sols)
     # without threading, segment 2 restarts at initial_step=1e-4 and needs
     # many doublings; with threading it continues at the grown step.
@@ -389,10 +392,7 @@ def test_direct_driver_time_cotangent_dtypes():
     """Drivers called directly (bypassing odeint's time coercion) must
     return time cotangents in the dtype the caller passed — here float32
     times under x64."""
-    from repro.core import (odeint_adjoint, odeint_symplectic,
-                            odeint_symplectic_adaptive,
-                            odeint_symplectic_saveat,
-                            odeint_symplectic_saveat_adaptive)
+    from repro.core import odeint_adjoint, symplectic_solve
     tab = get_tableau("dopri5")
     x0 = jnp.ones(3)
     t0, t1 = jnp.float32(0.0), jnp.float32(1.0)
@@ -401,21 +401,20 @@ def test_direct_driver_time_cotangent_dtypes():
 
     cases = {
         "sym": (lambda a, b: jnp.sum(
-            odeint_symplectic(linear, tab, 6, "auto", x0, a, b, LIN_P)),
+            symplectic_solve(linear, tab, 6, "auto", x0, a, b, LIN_P)),
             (t0, t1)),
         "syma": (lambda a, b: jnp.sum(
-            odeint_symplectic_adaptive(linear, tab, cfg, "auto",
-                                       x0, a, b, LIN_P)), (t0, t1)),
+            symplectic_solve(linear, tab, cfg, "auto", x0, a, b, LIN_P)),
+            (t0, t1)),
         "adj": (lambda a, b: jnp.sum(
             odeint_adjoint(linear, tab, 6, 1, "auto", x0, a, b, LIN_P)),
             (t0, t1)),
         "sym_saveat": (lambda a, b: jnp.sum(
-            odeint_symplectic_saveat(linear, tab, 4, "auto", x0, a, b,
-                                     LIN_P)), (t0, ts32)),
+            symplectic_solve(linear, tab, 4, "auto", x0, a, b, LIN_P,
+                             segmented=True)), (t0, ts32)),
         "syma_saveat": (lambda a, b: jnp.sum(
-            odeint_symplectic_saveat_adaptive(linear, tab, cfg, "auto",
-                                              x0, a, b, LIN_P)),
-            (t0, ts32)),
+            symplectic_solve(linear, tab, cfg, "auto", x0, a, b, LIN_P,
+                             segmented=True)), (t0, ts32)),
     }
     for name, (loss, targs) in cases.items():
         gts = jax.grad(loss, argnums=(0, 1))(*targs)

@@ -31,10 +31,7 @@ from repro.core import AdaptiveConfig
 from repro.core.rk import (rk_solve_adaptive, rk_solve_adaptive_batched,
                            rk_solve_fixed)
 from repro.core.stepper import AdaptiveStepper, FixedStepper
-from repro.core.symplectic import (_sym_bwd, _syma_bwd, _symab_bwd,
-                                   odeint_symplectic,
-                                   odeint_symplectic_adaptive,
-                                   odeint_symplectic_adaptive_batched)
+from repro.core.symplectic import symplectic_replay, symplectic_solve
 from repro.core.tableau import get_tableau
 
 TAB = get_tableau("dopri5")
@@ -136,10 +133,8 @@ def test_adaptive_pause_resume_gradients_bit_exact():
     def replay(state):
         sol = stepper.finalize(state)
         lam_N = jax.grad(loss)(sol.x_final)
-        res = (sol.xs, sol.ts, sol.hs, sol.n_accepted, PARAMS,
-               jnp.asarray(T0), jnp.asarray(T1))
-        lam0, _, _, gtheta = _syma_bwd(field, TAB, CFG, "auto", res, lam_N)
-        return lam0, gtheta
+        ckpt = (sol.xs, sol.ts, sol.hs, sol.n_accepted)
+        return symplectic_replay(field, TAB, ckpt, PARAMS, lam_N)
 
     uninterrupted, _ = drive(stepper, stepper.init_state(X0, T0, T1), PARAMS)
     paused, _ = drive(stepper, stepper.init_state(X0, T0, T1), PARAMS,
@@ -151,7 +146,7 @@ def test_adaptive_pause_resume_gradients_bit_exact():
     # and the replayed gradient agrees with end-to-end jax.grad through
     # the driver (same checkpoints up to the while-body fusion ulps)
     g_one = jax.grad(
-        lambda x0, p: loss(odeint_symplectic_adaptive(
+        lambda x0, p: loss(symplectic_solve(
             field, TAB, CFG, "auto", x0, T0, T1, p)),
         argnums=(0, 1))(X0, PARAMS)
     assert tree_allclose(g_one[0], g_paused[0], tol=1e-8)
@@ -207,8 +202,8 @@ def test_batched_pause_resume_bit_exact():
 
 def test_batched_pause_resume_gradients_bit_exact():
     g_one = jax.grad(
-        lambda x0, p: loss(odeint_symplectic_adaptive_batched(
-            field, TAB, CFG, "auto", x0, T0, T1_LANES, p)),
+        lambda x0, p: loss(symplectic_solve(
+            field, TAB, CFG, "auto", x0, T0, T1_LANES, p, lanes=True)),
         argnums=(0, 1))(X0_LANES, PARAMS)
 
     stepper = AdaptiveStepper(field, TAB, CFG)
@@ -217,9 +212,8 @@ def test_batched_pause_resume_gradients_bit_exact():
                      PARAMS, pause_after=3)
     sol = stepper.finalize(state)
     lam_N = jax.grad(loss)(sol.x_final)
-    res = (sol.xs, sol.ts, sol.hs, sol.n_accepted, PARAMS,
-           jnp.asarray(T0), jnp.asarray(T1_LANES))
-    lam0, _, _, gtheta = _symab_bwd(field, TAB, CFG, "auto", res, lam_N)
+    lam0, gtheta = symplectic_replay(
+        field, TAB, (sol.xs, sol.ts, sol.hs, sol.n_accepted), PARAMS, lam_N)
     assert tree_bits_equal(g_one[0], lam0)
     assert tree_bits_equal(g_one[1], gtheta)
 
@@ -248,7 +242,7 @@ def test_fixed_pause_resume_bit_exact():
 
 def test_fixed_pause_resume_gradients_bit_exact():
     g_one = jax.grad(
-        lambda x0, p: loss(odeint_symplectic(
+        lambda x0, p: loss(symplectic_solve(
             field, TAB, N_STEPS, "auto", x0, T0, T1, p)),
         argnums=(0, 1))(X0, PARAMS)
 
@@ -261,7 +255,7 @@ def test_fixed_pause_resume_gradients_bit_exact():
             state = save_restore(state)
     sol = stepper.finalize(state)
     lam_N = jax.grad(loss)(sol.x_final)
-    res = (sol.xs, sol.ts, sol.h, PARAMS, jnp.asarray(T0), jnp.asarray(T1))
-    lam0, _, _, gtheta = _sym_bwd(field, TAB, N_STEPS, "auto", res, lam_N)
+    lam0, gtheta = symplectic_replay(field, TAB, (sol.xs, sol.ts, sol.h),
+                                     PARAMS, lam_N)
     assert tree_bits_equal(g_one[0], lam0)
     assert tree_bits_equal(g_one[1], gtheta)

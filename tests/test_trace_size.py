@@ -1,7 +1,7 @@
 """Trace-size regression tests for the scan-segmented SaveAt drivers.
 
-The segmented drivers (``_segmented`` in core/odeint.py, the symplectic
-SaveAt custom-VJP pair, ``rk_solve_adaptive_saveat_stacked``) run their
+The segmented drivers (``_segmented`` in core/api.py, the symplectic
+driver's segmented forward and replay, ``rk_solve_segments``) run their
 per-observation segments inside ``lax.scan``, so the traced program is ONE
 segment body regardless of how many observation times the caller passes.
 These tests pin that property down as a jaxpr *equation count* invariant:
